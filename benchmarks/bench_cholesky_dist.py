@@ -1,66 +1,47 @@
 """Paper Fig. 3(b) analog: distributed Cholesky, UTP vs direct.
 
-Runs in a SUBPROCESS with ``--xla_force_host_platform_device_count=4`` so
-the DuctTeip-analog shard executor places level-1 blocks over a real
-4-device mesh (the paper's C7-C9 configs, scaled to this harness).
+Runs in this process on the devices present, on a ``(devices, 1)`` mesh:
+the DuctTeip-analog shard executor places level-1 block rows over the
+``data`` axis (the paper's C7-C9 configs, scaled to this harness).  For a
+multi-device run on a CPU host, start the process with
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
 """
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
+import jax
+import jax.numpy as jnp
 
-from .common import row
-
-_CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-import json, time
-import jax, jax.numpy as jnp
+from repro.compat import make_mesh
 from repro.core import spd_matrix
 from repro.linalg import run_cholesky
 
-mesh = jax.make_mesh((4, 1), ("data", "model"))
-out = {}
-n = 512
-a = spd_matrix(n)
-
-def t(fn):
-    fn(); t0 = time.perf_counter(); r = fn(); jax.block_until_ready(r)
-    return time.perf_counter() - t0
-
-out["direct"] = t(lambda: jnp.linalg.cholesky(a))
-out["g3flat_4dev"] = t(lambda: run_cholesky(a, graph="g3flat", partitions=((8, 8),), mesh=mesh))
-out["g3_4dev"] = t(lambda: run_cholesky(a, graph="g3", partitions=((4, 4), (2, 2)), mesh=mesh))
-out["g4_4dev"] = t(lambda: run_cholesky(a, graph="g4", partitions=((4, 4), (2, 2)), mesh=mesh))
-err = float(jnp.abs(run_cholesky(a, graph="g3", partitions=((4,4),(2,2)), mesh=mesh)
-                    - jnp.linalg.cholesky(a)).max())
-out["g3_max_err"] = err
-print("RESULT " + json.dumps(out))
-"""
+from .common import row, timeit
 
 
 def main(quick: bool = True) -> None:
-    env = dict(os.environ)
-    env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
-    proc = subprocess.run(
-        [sys.executable, "-c", _CHILD], capture_output=True, text=True, env=env,
-        timeout=900,
-    )
-    line = next(
-        (l for l in proc.stdout.splitlines() if l.startswith("RESULT ")), None
-    )
-    if line is None:
-        print(proc.stdout[-2000:])
-        print(proc.stderr[-2000:])
-        raise RuntimeError("distributed cholesky child failed")
-    out = json.loads(line[len("RESULT "):])
+    nd = jax.device_count()
+    mesh = make_mesh((nd, 1), ("data", "model"))
     n = 512
-    for k in ("direct", "g3flat_4dev", "g3_4dev", "g4_4dev"):
-        row(f"cholesky_dist_{k}_n{n}", out[k], f"{(n**3/3)/out[k]/1e9:.2f}GF/s")
-    row("cholesky_dist_g3_max_err", out["g3_max_err"] * 1e-6, "abs_err")
+    a = spd_matrix(n)
+    runs = {
+        "direct": lambda: jnp.linalg.cholesky(a),
+        "g3flat": lambda: run_cholesky(
+            a, graph="g3flat", partitions=((8, 8),), mesh=mesh
+        ),
+        "g3": lambda: run_cholesky(
+            a, graph="g3", partitions=((4, 4), (2, 2)), mesh=mesh
+        ),
+        "g4": lambda: run_cholesky(
+            a, graph="g4", partitions=((4, 4), (2, 2)), mesh=mesh
+        ),
+    }
+    for k, fn in runs.items():
+        t = timeit(fn, iters=1 if quick else 3)
+        name = k if k == "direct" else f"{k}_{nd}dev"
+        row(f"cholesky_dist_{name}_n{n}", t, f"{(n**3/3)/t/1e9:.2f}GF/s")
+    err = float(jnp.abs(runs["g3"]() - jnp.linalg.cholesky(a)).max())
+    row("cholesky_dist_g3_max_err", err * 1e-6, "abs_err")
 
 
 if __name__ == "__main__":

@@ -188,4 +188,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compat import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -25,6 +25,7 @@ sys.path.insert(0, "src")
 import jax
 import jax.numpy as jnp
 
+from repro.compat import enable_compile_cache, make_mesh
 from repro.core import Dispatcher, GData, dd_matrix, utp_get_parameters
 from repro.core.executors import clear_compile_cache
 from repro.linalg import run_inv, run_lu_solve
@@ -49,7 +50,7 @@ def main():
         mesh = None
         if graph == "g3":
             nd = jax.device_count()
-            mesh = jax.make_mesh((nd, 1), ("data", "model"))
+            mesh = make_mesh((nd, 1), ("data", "model"))
         x = run_lu_solve(a, b, graph=graph, partitions=parts, mesh=mesh)
         err = float(jnp.abs(x - want).max())
         print(f"  graph {graph:4s} max_err={err:.2e}")
@@ -82,4 +83,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -16,6 +16,7 @@ sys.path.insert(0, "src")
 import jax
 import jax.numpy as jnp
 
+from repro.compat import enable_compile_cache, make_mesh
 from repro.core import Dispatcher, GData, GTask, spd_matrix, utp_get_parameters
 from repro.linalg import POTRF, utp_cholesky
 
@@ -35,7 +36,7 @@ def main():
         mesh = None
         if graph == "g3":
             nd = jax.device_count()
-            mesh = jax.make_mesh((nd, 1), ("data", "model"))
+            mesh = make_mesh((nd, 1), ("data", "model"))
         # ---- the application program (identical for every graph) --------
         d = Dispatcher(graph=graph, mesh=mesh)
         A = GData(a.shape, partitions=parts, dtype=a.dtype, value=a)
@@ -51,4 +52,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -13,6 +13,7 @@ sys.path.insert(0, "src")
 import jax
 import numpy as np
 
+from repro.compat import enable_compile_cache
 from repro.configs import get_arch
 from repro.models import build_model
 from repro.serving import EngineConfig, Request, ServeEngine
@@ -56,4 +57,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -26,6 +26,7 @@ sys.path.insert(0, "src")
 import jax
 
 from repro import optim
+from repro.compat import enable_compile_cache, make_mesh
 from repro.configs import get_arch
 from repro.configs.base import ShapeConfig
 from repro.train import Trainer, TrainerConfig
@@ -69,7 +70,7 @@ def main():
 
     shape = ShapeConfig("train", seq_len=args.seq, global_batch=args.batch,
                         kind="train")
-    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
     trainer = Trainer(
         cfg, shape, mesh,
         TrainerConfig(steps=args.steps, ckpt_every=max(args.steps // 4, 10),
@@ -96,4 +97,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

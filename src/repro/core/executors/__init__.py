@@ -5,6 +5,7 @@ from .jit_wave import (
     PallasExecutor,
     clear_compile_cache,
     drain_memo_pressure,
+    drain_memo_records,
     drain_memo_stats,
     set_drain_memo_capacity,
 )
@@ -21,6 +22,7 @@ __all__ = [
     "build_program",
     "clear_compile_cache",
     "drain_memo_pressure",
+    "drain_memo_records",
     "drain_memo_stats",
     "group_wave",
     "plan_schedule",

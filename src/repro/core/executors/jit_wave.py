@@ -170,6 +170,12 @@ def drain_memo_stats() -> Dict[str, int]:
     return _DRAIN_MEMO.stats()
 
 
+def drain_memo_records() -> List["ProgramRecord"]:
+    """Every captured program record in the global drain memo, oldest
+    first — lets a caller lower a drain's program and inspect its HLO."""
+    return [r for entry in _DRAIN_MEMO.values() for r in entry["records"]]
+
+
 def drain_memo_pressure(fraction: float = 0.5) -> int:
     """Shed the LRU ``fraction`` of the global drain memo (DESIGN.md §14).
 
@@ -425,6 +431,11 @@ class JitWaveExecutor(Executor):
         """Sharding for ``data``'s resident (nr, nc, br, bc) grid, or None."""
         return None
 
+    def _wrap_program(self):
+        """Hook: a wrapper applied to every traced program before jit, or
+        None (ShardExecutor runs Pallas programs inside ``shard_map``)."""
+        return None
+
     def _enter_grids(self, datas: Sequence[GData], blocks):
         """Enter grid epochs (resident re-entry is free) and apply grid
         shardings; returns (grids, shardings)."""
@@ -467,7 +478,12 @@ class JitWaveExecutor(Executor):
         fn = self._fn_cache.get(key)
         if fn is None:
             fn = build_program(
-                plan, self.backend, self.donate, out_shardings, batch=batch
+                plan,
+                self.backend,
+                self.donate,
+                out_shardings,
+                batch=batch,
+                wrap=self._wrap_program(),
             )
             self._fn_cache[key] = fn
             self.stats["compiles"] += 1
@@ -557,6 +573,9 @@ class JitWaveExecutor(Executor):
         jit_kwargs = {}
         if out_shardings is not None:
             jit_kwargs["out_shardings"] = out_shardings
+        wrap = self._wrap_program()
+        if wrap is not None:
+            fn = wrap(fn)
         return jax.jit(fn, donate_argnums=(0,) if self.donate else (), **jit_kwargs)
 
     def _group_fn(self, op, rep: GTask, roots_order: Tuple[int, ...]):

@@ -8,6 +8,13 @@ XLA's SPMD partitioner materializes the panel movements as collectives
 (all-gather / collective-permute) — explicit, inspectable in the HLO, and
 overlappable by the latency-hiding scheduler.
 
+Pallas (Mosaic) kernels cannot be partitioned automatically, so under the
+``pallas`` backend (g4) each program runs inside ``shard_map`` with every
+argument replicated: XLA all-gathers the row-sharded grids on entry, every
+device runs the whole schedule, and the jit's output shardings slice the
+result back to owned block rows.  Distributing the compute itself is left
+to a later change; the all-gather per drain is the known cost.
+
 ``shard_axes`` picks which array dims map to which mesh axes; divisibility
 is checked and falls back to replication per-dim (never fails to place).
 """
@@ -19,6 +26,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ...compat import shard_map
 from ..data import GData
 from ..task import GTask
 from .jit_wave import JitWaveExecutor
@@ -66,6 +74,13 @@ class ShardExecutor(JitWaveExecutor):
             tuple(d.id for d in self.mesh.devices.flat),
         )
         return super().memo_key_extra() + (mesh_desc, tuple(self.shard_axes))
+
+    def _wrap_program(self):
+        if self.backend != "pallas":
+            return None
+        return lambda fn: shard_map(
+            fn, mesh=self.mesh, in_specs=P(), out_specs=P(), check_vma=False
+        )
 
     def _grid_sharding(self, data: GData, br: int, bc: int):
         """Shard the resident (nr, nc, br, bc) grid over its *grid* dims.

@@ -377,8 +377,12 @@ def build_program(
     donate: bool,
     out_shardings=None,
     batch: Optional[int] = None,
+    wrap=None,
 ):
     """Trace ``plan`` into one jitted fn: (grids, idx_array) -> grids'.
+
+    ``wrap``, when given, maps the traced program to the function that is
+    jitted (``ShardExecutor`` runs Pallas programs inside ``shard_map``).
 
     With ``batch=B`` the SAME plan is traced in stacked form (DESIGN.md §7):
     every root grid carries a leading batch dimension ``(B, nr, nc, br, bc)``
@@ -503,6 +507,8 @@ def build_program(
     jit_kwargs = {}
     if out_shardings is not None:
         jit_kwargs["out_shardings"] = out_shardings
+    if wrap is not None:
+        program = wrap(program)
     return jax.jit(
         program, donate_argnums=(0,) if donate else (), **jit_kwargs
     )

@@ -12,7 +12,9 @@ and the blocked right-looking pivot-free LU (DESIGN.md §6):
     trsmul(u, b)    -> inv(triu(u)) @ b         (left, upper, non-unit)
     gemmnn(a, b, c) -> c - a @ b
     lu_solve(a, b)  -> (packed L\\U of a, x with a @ x == b)
-All oracles compute in float32 and cast back to the input dtype.  The
+All oracles compute in float32 (matmuls at ``Precision.HIGHEST``, so a TPU
+does not round f32 operands to bf16 passes) and cast back to the input
+dtype.  The
 triangular-solve oracles read only their own triangle (plus U's diagonal),
 so packed L\\U blocks can be passed without masking.
 """
@@ -28,6 +30,10 @@ def _f32(x):
     return x.astype(jnp.float32)
 
 
+def _mm(a, b):
+    return jnp.matmul(_f32(a), _f32(b), precision=jax.lax.Precision.HIGHEST)
+
+
 def potrf(a: jnp.ndarray) -> jnp.ndarray:
     return jnp.linalg.cholesky(_f32(a)).astype(a.dtype)
 
@@ -38,11 +44,11 @@ def trsm(l: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 
 
 def syrk(a: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
-    return (_f32(c) - _f32(a) @ _f32(a).T).astype(c.dtype)
+    return (_f32(c) - _mm(a, _f32(a).T)).astype(c.dtype)
 
 
 def gemm(a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
-    return (_f32(c) - _f32(a) @ _f32(b).T).astype(c.dtype)
+    return (_f32(c) - _mm(a, _f32(b).T)).astype(c.dtype)
 
 
 def getrf(a: jnp.ndarray) -> jnp.ndarray:
@@ -76,7 +82,7 @@ def trsmul(u: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 
 
 def gemmnn(a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
-    return (_f32(c) - _f32(a) @ _f32(b)).astype(c.dtype)
+    return (_f32(c) - _mm(a, b)).astype(c.dtype)
 
 
 def lu_solve(a: jnp.ndarray, b: jnp.ndarray):
@@ -90,7 +96,7 @@ def lu_solve(a: jnp.ndarray, b: jnp.ndarray):
 
 
 def matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    return (_f32(a) @ _f32(b)).astype(a.dtype)
+    return _mm(a, b).astype(a.dtype)
 
 
 def flash_attention(
@@ -108,7 +114,9 @@ def flash_attention(
     scale = (D ** -0.5) if scale is None else scale
     kq = jnp.repeat(_f32(k), g, axis=1)
     vq = jnp.repeat(_f32(v), g, axis=1)
-    logits = jnp.einsum("bhqd,bhkd->bhqk", _f32(q) * scale, kq)
+    logits = jnp.einsum(
+        "bhqd,bhkd->bhqk", _f32(q) * scale, kq, precision=jax.lax.Precision.HIGHEST
+    )
     qi = jnp.arange(S)[:, None]
     ki = jnp.arange(S)[None, :]
     mask = jnp.ones((S, S), dtype=bool)
@@ -118,4 +126,6 @@ def flash_attention(
         mask &= ki > qi - window
     logits = jnp.where(mask, logits, -jnp.inf)
     p = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, vq).astype(q.dtype)
+    return jnp.einsum(
+        "bhqk,bhkd->bhqd", p, vq, precision=jax.lax.Precision.HIGHEST
+    ).astype(q.dtype)

@@ -45,34 +45,73 @@ def _stack_spec(shape):
 # --------------------------------------------------------------------------
 # Tile bodies — pure (b, b) math shared by the batched per-tile kernels and
 # the fused grid kernels below.
+#
+# The panel bodies are written for Mosaic: every value is 2-D, and a row or
+# column at the loop index is selected with a broadcasted-iota mask and a
+# reduction, never by dynamic slicing or indexed update of a traced value
+# (Mosaic cannot lower ``dynamic_slice`` on values).  Each step is a masked
+# rank-1 update of the whole tile on the VPU.
 # --------------------------------------------------------------------------
+def _iota(shape, dim):
+    return lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _col(m, j):
+    """Column ``j`` of 2-D ``m`` as an (r, 1) array."""
+    return jnp.sum(jnp.where(_iota(m.shape, 1) == j, m, 0.0), axis=1, keepdims=True)
+
+
+def _row(m, i):
+    """Row ``i`` of 2-D ``m`` as a (1, c) array."""
+    return jnp.sum(jnp.where(_iota(m.shape, 0) == i, m, 0.0), axis=0, keepdims=True)
+
+
+def _transpose_col(v):
+    """(n, 1) -> (1, n) through a diagonal mask (no vector transpose)."""
+    n = v.shape[0]
+    diag = _iota((n, n), 0) == _iota((n, n), 1)
+    return jnp.sum(jnp.where(diag, v, 0.0), axis=0, keepdims=True)
+
+
 def _potrf_tile(a: jnp.ndarray) -> jnp.ndarray:
+    """Lower Cholesky factor of one tile (reads the lower triangle only).
+
+    Right-looking: step j takes column j of the updated tile as L[:, j] and
+    subtracts its outer product from the trailing block.
+    """
     a = a.astype(jnp.float32)
     b = a.shape[-1]
-    idx = jnp.arange(b)
+    rows, cols = _iota((b, b), 0), _iota((b, b), 1)
+    rv = _iota((b, 1), 0)
 
-    def body(j, L):
-        # s[i] = sum_{k<j} L[i,k] * L[j,k]  (columns >= j of L are still zero)
-        s = L @ L[j]
-        djj = jnp.sqrt(a[j, j] - s[j])
-        col = (a[:, j] - s) / djj
-        col = jnp.where(idx > j, col, 0.0)
-        col = col.at[j].set(djj)
-        return L.at[:, j].set(col)
+    def body(j, m):
+        c = _col(m, j)
+        d = jnp.sqrt(_row(c, j))  # (1, 1) pivot
+        l = jnp.where(rv > j, c / d, jnp.where(rv == j, d, 0.0))
+        trail = (rows > j) & (cols > j)
+        m = jnp.where(trail, m - l * _transpose_col(l), m)
+        return jnp.where(cols == j, l, m)
 
-    return lax.fori_loop(0, b, body, jnp.zeros_like(a))
+    m = lax.fori_loop(0, b, body, a)
+    return jnp.where(rows >= cols, m, 0.0)
 
 
 def _trsm_tile(L: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
+    """X = B @ inv(L)^T, L lower non-unit.
+
+    Column recurrence: X[:, j] = (B[:, j] - X @ L[j]) / L[j, j]; columns
+    >= j of X are still zero, so L's upper triangle multiplies zeros.
+    """
     L = L.astype(jnp.float32)
     B = B.astype(jnp.float32)
     nb = L.shape[-1]
+    cols = _iota(B.shape, 1)
 
     def body(j, X):
-        # (X L^T)[:, j] = sum_{k<=j} X[:,k] L[j,k]; cols >= j of X still zero
-        s = X @ L[j]
-        col = (B[:, j] - s) / L[j, j]
-        return X.at[:, j].set(col)
+        lj = _row(L, j)  # (1, nb)
+        s = jnp.sum(X * lj, axis=1, keepdims=True)
+        x = (_col(B, j) - s) / _col(lj, j)
+        return jnp.where(cols == j, x, X)
 
     return lax.fori_loop(0, nb, body, jnp.zeros_like(B))
 
@@ -86,14 +125,15 @@ def _getrf_tile(a: jnp.ndarray) -> jnp.ndarray:
     """
     a = a.astype(jnp.float32)
     b = a.shape[-1]
-    idx = jnp.arange(b)
+    rows, cols = _iota((b, b), 0), _iota((b, b), 1)
+    rv, cv = _iota((b, 1), 0), _iota((1, b), 1)
 
     def body(k, m):
-        col = jnp.where(idx > k, m[:, k] / m[k, k], m[:, k])
-        m = m.at[:, k].set(col)
-        l = jnp.where(idx > k, col, 0.0)
-        u = jnp.where(idx > k, m[k, :], 0.0)
-        return m - l[:, None] * u[None, :]
+        c = _col(m, k)
+        l = jnp.where(rv > k, c / _row(c, k), 0.0)
+        u = jnp.where(cv > k, _row(m, k), 0.0)
+        m = jnp.where((cols == k) & (rows > k), l, m)
+        return m - l * u
 
     return lax.fori_loop(0, b, body, a)
 
@@ -101,72 +141,82 @@ def _getrf_tile(a: jnp.ndarray) -> jnp.ndarray:
 def _trsml_tile(L: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
     """X = inv(L) @ B with L unit-lower (stored diagonal/upper ignored).
 
-    Row recurrence: X[i] = B[i] - L[i] @ X.  Rows >= i of X are still zero,
-    so the packed block's diagonal and upper junk multiply zeros — no
-    masking needed (same trick as ``_trsm_tile``).
+    Right-looking row recurrence: once row i of X is final, subtract
+    L[k, i] * X[i] from every row k > i.  Only L's strict lower triangle is
+    read, so packed L\\U blocks pass unmasked.
     """
     L = L.astype(jnp.float32)
     B = B.astype(jnp.float32)
     nb = L.shape[-1]
+    rows = _iota(B.shape, 0)
 
     def body(i, X):
-        return X.at[i].set(B[i] - L[i] @ X)
+        return jnp.where(rows > i, X - _col(L, i) * _row(X, i), X)
 
-    return lax.fori_loop(0, nb, body, jnp.zeros_like(B))
+    return lax.fori_loop(0, nb, body, B)
 
 
 def _trsmu_tile(U: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
     """X = B @ inv(U) with U upper non-unit (stored lower junk ignored).
 
-    Column recurrence: X[:, j] = (B[:, j] - X @ U[:, j]) / U[j, j]; columns
-    >= j of X are still zero, masking U's sub-diagonal content.
+    Right-looking column recurrence: X[:, j] = X[:, j] / U[j, j], then
+    subtract X[:, j] * U[j, k] from every column k > j.  Only U's upper
+    triangle is read.
     """
     U = U.astype(jnp.float32)
     B = B.astype(jnp.float32)
     nb = U.shape[-1]
+    cols = _iota(B.shape, 1)
 
     def body(j, X):
-        s = X @ U[:, j]
-        return X.at[:, j].set((B[:, j] - s) / U[j, j])
+        uj = _row(U, j)  # (1, nb)
+        x = _col(X, j) / _col(uj, j)
+        return jnp.where(cols > j, X - x * uj, jnp.where(cols == j, x, X))
 
-    return lax.fori_loop(0, nb, body, jnp.zeros_like(B))
+    return lax.fori_loop(0, nb, body, B)
 
 
 def _trsmul_tile(U: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
     """X = inv(U) @ B with U upper non-unit (stored lower junk ignored).
 
-    Bottom-up row recurrence: X[i] = (B[i] - U[i] @ X) / U[i, i].  Rows
-    <= i of X are still zero when row i is computed, so U's sub-diagonal
-    content multiplies zeros — packed L\\U blocks pass unmasked (same trick
-    as ``_trsml_tile``, run in reverse row order).
+    Bottom-up right-looking row recurrence: X[i] = X[i] / U[i, i], then
+    subtract U[k, i] * X[i] from every row k < i.  Only U's upper triangle
+    is read, so packed L\\U blocks pass unmasked.
     """
     U = U.astype(jnp.float32)
     B = B.astype(jnp.float32)
     nb = U.shape[-1]
+    rows = _iota(B.shape, 0)
 
-    def body(j, X):
-        i = nb - 1 - j
-        s = U[i] @ X
-        return X.at[i].set((B[i] - s) / U[i, i])
+    def body(t, X):
+        i = nb - 1 - t
+        ui = _col(U, i)  # (nb, 1)
+        x = _row(X, i) / _row(ui, i)
+        return jnp.where(rows < i, X - ui * x, jnp.where(rows == i, x, X))
 
-    return lax.fori_loop(0, nb, body, jnp.zeros_like(B))
+    return lax.fori_loop(0, nb, body, B)
+
+
+# f32 operands through the MXU at full precision (Mosaic's default for a
+# dot is bf16 passes)
+_HIGHEST = lax.Precision.HIGHEST
 
 
 def _gemmnn_tile(a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
     return c.astype(jnp.float32) - jnp.dot(
-        a, b, preferred_element_type=jnp.float32
+        a, b, preferred_element_type=jnp.float32, precision=_HIGHEST
     )
 
 
 def _syrk_tile(a: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
     return c.astype(jnp.float32) - jnp.dot(
-        a, a.T, preferred_element_type=jnp.float32
+        a, a.T, preferred_element_type=jnp.float32, precision=_HIGHEST
     )
 
 
 def _gemm_tile(a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
     return c.astype(jnp.float32) - jnp.dot(
-        a, b.T, preferred_element_type=jnp.float32
+        a, b.T, preferred_element_type=jnp.float32, precision=_HIGHEST
     )
 
 
@@ -356,13 +406,13 @@ def batched_gemmnn(
 #
 # Gather -> compute -> scatter in ONE kernel over the resident
 # ``(nr, nc, br, bc)`` grid: per-task block coordinates arrive as
-# scalar-prefetched ``(n, 2)`` int32 arrays, the BlockSpec index maps DMA the
-# addressed blocks straight from the grid into VMEM, and the output aliases
-# the written arg's grid so the scatter is in place — no gathered tile
-# stacks ever materialize in HBM.  Callers must pass exact (unpadded) group
-# sizes: tasks in a group are independent, so distinct write blocks are
-# guaranteed, but duplicated trailing indices would re-read their own
-# scatter for read-write operations.
+# scalar-prefetched int32 arrays (``(n, 2)`` flattened), the BlockSpec index
+# maps DMA the addressed blocks straight from the grid into VMEM, and the
+# output aliases the written arg's grid so the scatter is in place — no
+# gathered tile stacks ever materialize in HBM.  Callers must pass exact
+# (unpadded) group sizes: tasks in a group are independent, so distinct
+# write blocks are guaranteed, but duplicated trailing indices would re-read
+# their own scatter for read-write operations.
 # --------------------------------------------------------------------------
 def make_grid_fused(tile_fn, arity: int, write_arg: int):
     """Build a fused gather/compute/scatter entry point for ``tile_fn``.
@@ -391,17 +441,20 @@ def make_grid_fused(tile_fn, arity: int, write_arg: int):
         out = tile_fn(*(r[0, 0, 0] for r in in_refs))
         o_ref[0, 0, 0, :, :] = out.astype(o_ref.dtype)
 
+    # block coordinates arrive flattened to (2n,) int32: a 2-D (n, 2) SMEM
+    # array pads every row to 128 words, which overflows the 1 MiB SMEM at
+    # group sizes of a few hundred
     def _imap(a: int):
         def imap(i, *idx_refs):
             r = idx_refs[a]
-            return (r[i, 0], r[i, 1], 0, 0)
+            return (r[2 * i], r[2 * i + 1], 0, 0)
 
         return imap
 
     def _imap_stacked(a: int):
         def imap(b, i, *idx_refs):
             r = idx_refs[a]
-            return (b, r[i, 0], r[i, 1], 0, 0)
+            return (b, r[2 * i], r[2 * i + 1], 0, 0)
 
         return imap
 
@@ -436,7 +489,7 @@ def make_grid_fused(tile_fn, arity: int, write_arg: int):
             out_shape=jax.ShapeDtypeStruct(wg.shape, wg.dtype),
             input_output_aliases={arity + write_arg: 0},
             interpret=_resolve(interpret),
-        )(*idxs, *grids)
+        )(*(ix.reshape(-1) for ix in idxs), *grids)
 
     return call
 
@@ -476,7 +529,10 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
+        a_ref[...],
+        b_ref[...],
+        preferred_element_type=jnp.float32,
+        precision=_HIGHEST if a_ref.dtype == jnp.float32 else None,
     )
 
     @pl.when(pl.program_id(2) == nk - 1)

@@ -17,11 +17,13 @@ from typing import Optional, Tuple
 import jax
 from jax.sharding import Mesh
 
+from ..compat import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(
@@ -33,7 +35,7 @@ def make_local_mesh(
         model = 1
     if data is None:
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def mesh_axes(mesh: Mesh) -> Tuple[str, ...]:

@@ -6,12 +6,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.compat import make_mesh
 from repro.core import GRAPHS, Dispatcher, GData, spd_matrix
 from repro.linalg import run_cholesky
 
 
 def _mesh_1d():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("graph", ["g1", "g2", "g2p"])

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.compat import make_mesh
 from repro.configs import ARCHS, SHAPES, get_arch
 from repro.configs.base import ShapeConfig
 from repro.launch import sharding as sh
@@ -15,14 +16,14 @@ from repro.launch.steps import make_decode_step, make_prefill_step, make_train_s
 
 
 def mesh2():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # --------------------------------------------------------------------------
 # resolver
 # --------------------------------------------------------------------------
 def test_resolver_divisibility_fallback():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = sh.Rules(table={"heads": ("model",), "embed": ("data",), None: ()})
     # divisible -> sharded (axis size 1 divides everything)
     spec = sh.resolve_pspec(("embed", "heads", None), (64, 8, 16), mesh, rules)

@@ -21,6 +21,7 @@ from jax.scipy.linalg import (
     solve_triangular,
 )
 
+from repro.compat import make_mesh
 from repro.core import Dispatcher, GData, OpRegistry, dd_matrix, utp_get_parameters
 from repro.core.executors import clear_compile_cache
 from repro.linalg import run_inv, run_lu, run_lu_solve, run_solve
@@ -28,7 +29,7 @@ from repro.linalg.lu import utp_getrf, utp_lu_solve
 
 
 def _mesh_1d():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _lu_ref(a):

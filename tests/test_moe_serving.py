@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.compat import make_mesh
 from repro.configs import ARCHS
 from repro.models import build_model
 from repro.models.layers import init_params
@@ -40,7 +41,7 @@ def test_ep_matches_local():
     p = make_params(cfg)
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 16, cfg.d_model)) * 0.5
     out_local, aux_local = moe_apply(cfg, p, x)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ctx = MoeCtx(mesh=mesh, batch_axes=("data",), model_axis="model")
     with mesh:
         out_ep, aux_ep = jax.jit(lambda pp, xx: moe_apply(cfg, pp, xx, ctx=ctx))(p, x)
@@ -52,7 +53,7 @@ def test_ep_grads_flow():
     cfg = moe_cfg()
     p = make_params(cfg)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 8, cfg.d_model)) * 0.5
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ctx = MoeCtx(mesh=mesh, batch_axes=("data",), model_axis="model")
 
     def loss(pp):

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.compat import make_mesh
 from repro import optim
 from repro.configs import ARCHS
 from repro.configs.base import ShapeConfig
@@ -69,7 +70,7 @@ def test_checkpoint_async(tmp_path):
 def test_checkpoint_elastic_resharding(tmp_path):
     """Save, then restore with an explicit (trivial) sharding tree — the
     elastic path used when the mesh changes between runs."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     ck = Checkpointer(str(tmp_path))
@@ -87,7 +88,7 @@ def test_checkpoint_elastic_resharding(tmp_path):
 def small_trainer(tmp_path, steps=12, ckpt_every=4):
     cfg = tiny_cfg()
     shape = ShapeConfig("t", seq_len=32, global_batch=4, kind="train")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     t = Trainer(
         cfg, shape, mesh,
         TrainerConfig(

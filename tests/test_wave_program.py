@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.compat import make_mesh
 from repro.core import Access, Dispatcher, GData, GTask, Operation, spd_matrix
 from repro.core.data import from_grid, to_grid
 from repro.core.executors import (
@@ -25,7 +26,7 @@ from repro.linalg import run_cholesky
 
 
 def _mesh_1d():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # --------------------------------------------------------------------------
