@@ -1,0 +1,7 @@
+"""Pallas tile kernels: the `gemm` kernel's share of its roofline, %."""
+
+from bench.program_trace import kernel_roofline
+
+
+def read(ctx):
+    return kernel_roofline(ctx, "gemm")

@@ -30,6 +30,7 @@ from .executors.jit_wave import _DRAIN_MEMO, JitWaveExecutor, PallasExecutor
 from .executors.sharded import ShardExecutor
 from .graph import TaskFlowGraph, get_graph
 from .task import GTask, TaskState
+from .tracing import span
 from .versioning import DepTracker, InFlightEpoch
 
 
@@ -207,23 +208,31 @@ class Dispatcher:
         this is what makes repeated drains (training steps, iterative
         solvers, benchmark sweeps) cost one compiled-program dispatch.
         """
+        roots, self._pending_roots = self._pending_roots, []
+        before = self.finished_count
+        self._drain_keys = []
+        with span("utp.drain", roots=len(roots)) as sp:
+            sp.counts["memo_hit"] = self._drain(roots)
+            sp.counts["leaves"] = self.finished_count - before
+        return self.finished_count - before
+
+    def _drain(self, roots: List[GTask]) -> int:
+        """The body of ``run``; returns 1 when the drain replayed from the
+        memo, else 0."""
         # Homogeneous-root stacking (DESIGN.md §7): N structurally identical
         # roots drain as ONE batched program over a pow2-bucketed batch
         # axis; the returned leaf count is then the TEMPLATE's (each leaf
         # computes all N lanes at once).  Heterogeneous streams keep the
         # PR-3 path: per-root expansion + cross-root segment fusion.
-        roots, self._pending_roots = self._pending_roots, []
-        before = self.finished_count
-        self._drain_keys = []
         if self.stack_roots and self._stackable(roots):
-            if self._run_stacked(roots):
-                return self.finished_count - before
+            return self._run_stacked(roots)
+        before = self.finished_count
         key = self._drain_memo_key(roots)
         memo = _DRAIN_MEMO.get(key) if key is not None else None
         if memo is not None:
             self.stats["memo_hits"] += 1
             self._replay_drain(memo, roots)
-            return self.finished_count - before
+            return 1
         if key is not None:
             self.stats["memo_misses"] += 1
         capturing = key is not None
@@ -235,7 +244,7 @@ class Dispatcher:
             stats_before = (self.stats["split"], self.stats["waves"])
             self._capture_valid = True
         try:
-            self._process_scope(roots, level=0)
+            self._split(roots)
         except BaseException:
             # failed drain hardening (DESIGN.md §10): discard the partial
             # capture so no half-captured entry can reach the drain memo
@@ -253,7 +262,7 @@ class Dispatcher:
                     "waves": self.stats["waves"] - stats_before[1],
                 }
                 self._drain_keys.append(key)
-        return self.finished_count - before
+        return 0
 
     def run_async(self) -> DrainHandle:
         """Drain all submitted tasks WITHOUT fencing device execution.
@@ -319,7 +328,7 @@ class Dispatcher:
                 arg_pos.append(j)
         return [[r.args[j].data for r in roots] for j in arg_pos]
 
-    def _run_stacked(self, roots: List[GTask]) -> bool:
+    def _run_stacked(self, roots: List[GTask]) -> int:
         """Drain a homogeneous root stream as ONE batched program set.
 
         Only the TEMPLATE root (roots[0]) is expanded — splitting is a pure
@@ -328,8 +337,8 @@ class Dispatcher:
         programs and the drain-memo key is independent of the exact N.
         Falls back internally (template schedules as plain programs +
         remaining roots as a normal sub-drain) when the executor cannot
-        take the whole-program stacked path; always returns True once the
-        drain has been handled."""
+        take the whole-program stacked path.  Returns 1 when the drain
+        replayed from the memo, else 0."""
         template = roots[0]
         n = len(roots)
         bucket = 1
@@ -362,7 +371,7 @@ class Dispatcher:
             self.stats["split"] += memo["split"]
             self.stats["waves"] += memo["waves"]
             self.finished_count += memo["leaf_total"]
-            return True
+            return 1
         capturing = key is not None
         stats_before = (self.stats["split"], self.stats["waves"])
         if capturing:
@@ -374,7 +383,7 @@ class Dispatcher:
             self._capture_valid = True
         schedules: List[tuple] = []
         try:
-            self._process_scope([template], level=0, collect=schedules)
+            self._split([template], collect=schedules)
         except _StackedAbort:
             done = None
         except BaseException:
@@ -405,10 +414,10 @@ class Dispatcher:
             if capturing:
                 self.executor.end_capture()
             self.stats["split"], self.stats["waves"] = stats_before
-            self._process_scope(roots, level=0)
+            self._split(roots)
             for t in roots:
                 t.state = TaskState.FINISHED
-            return True
+            return 0
         self.stats["stacked_drains"] += 1
         if capturing:
             records, ok = self.executor.end_capture()
@@ -422,7 +431,7 @@ class Dispatcher:
                 self._drain_keys.append(key)
         for t in roots:
             t.state = TaskState.FINISHED
-        return True
+        return 0
 
     @staticmethod
     def _root_datas(roots: List[GTask]) -> List:
@@ -488,6 +497,14 @@ class Dispatcher:
         self.finished_count += memo["leaf_total"]
 
     # -- internal --------------------------------------------------------------
+    def _split(self, roots: List[GTask], collect: Optional[List] = None) -> None:
+        """Expand the roots down to the leaf schedule (and run it, unless
+        ``collect`` gathers it), under one ``utp.split`` span."""
+        split = self.stats["split"]
+        with span("utp.split") as sp:
+            self._process_scope(roots, level=0, collect=collect)
+            sp.counts["split"] = self.stats["split"] - split
+
     def _on_finished(self, task: GTask) -> None:
         self.finished_count += 1
         parent = task.parent

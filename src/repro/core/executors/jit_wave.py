@@ -41,6 +41,7 @@ from ...analysis.verify import verify_plan
 from ...testing import faults
 from ..data import GData, StackedEpoch, from_grid, to_grid
 from ..task import GTask, TaskState
+from ..tracing import span
 from ..versioning import InFlightEpoch
 from .base import Executor, group_wave
 from .wave_program import SchedulePlan, build_program, plan_schedule
@@ -249,13 +250,6 @@ class JitWaveExecutor(Executor):
         eps, self.inflight = self.inflight, []
         return eps
 
-    def sync(self) -> float:
-        """Fence all outstanding launches; accumulates the blocked host
-        seconds into ``stats['host_block_us']``."""
-        blocked = super().sync()
-        self.stats["host_block_us"] += int(blocked * 1e6)
-        return blocked
-
     # -- drain capture/replay protocol (DESIGN.md §2) --------------------------
     def memo_key_extra(self) -> tuple:
         """Executor-identity part of the dispatcher's drain-memo key."""
@@ -291,20 +285,20 @@ class JitWaveExecutor(Executor):
         faults.fire(
             "launch.oom", batch=rec.batch, n_tasks=rec.n_tasks, replay=True,
         )
-        if rec.batch is not None:
-            grids = self._stack_grids(datas, rec.blocks, rec.batch)
+        with span("utp.enter_grid", roots=len(datas)):
+            if rec.batch is not None:
+                grids = self._stack_grids(datas, rec.blocks, rec.batch)
+            else:
+                grids, _ = self._enter_grids(datas, rec.blocks)
+        with span("utp.launch", tasks=rec.n_tasks, groups=rec.n_groups):
             outs = rec.fn(grids, rec.idxs)
-            outs = faults.corrupt(
-                "executor.output", outs, batch=rec.batch, replay=True
-            )
+        outs = faults.corrupt(
+            "executor.output", outs, batch=rec.batch, replay=True
+        )
+        if rec.batch is not None:
             self._note_launch(outs, f"replay:stacked{rec.batch}")
             self._adopt_stacked(datas, outs, rec.blocks)
         else:
-            grids, _ = self._enter_grids(datas, rec.blocks)
-            outs = rec.fn(grids, rec.idxs)
-            outs = faults.corrupt(
-                "executor.output", outs, batch=None, replay=True
-            )
             self._note_launch(outs, "replay")
             for data, g in zip(datas, outs):
                 data.set_grid(g)
@@ -322,7 +316,7 @@ class JitWaveExecutor(Executor):
         if not waves:
             return 0
         self._prepare_roots(waves)
-        plan = plan_schedule(waves, dag)
+        plan = self._plan(waves, dag)
         if plan is None:
             self._capture_ok = False
             n = 0
@@ -333,8 +327,7 @@ class JitWaveExecutor(Executor):
             # prove the plan before launching it (DESIGN.md §11); verdicts
             # cache on (structural key, index digest) so a structurally
             # repeated drain pays one dict probe here
-            verify_plan(plan, dag)
-            self.stats["verified_plans"] += 1
+            self._verify(plan, dag)
         return self._run_program(plan)
 
     def execute_waves(self, waves: List[List[GTask]]) -> int:
@@ -361,7 +354,7 @@ class JitWaveExecutor(Executor):
             waves = [w for w in waves if w]
             if not waves:
                 continue
-            plan = plan_schedule(waves, dag)
+            plan = self._plan(waves, dag)
             if plan is None or any(
                 d not in members for d in plan.roots_order
             ):
@@ -370,13 +363,27 @@ class JitWaveExecutor(Executor):
                 # all template plans are proven up front, before ANY lane
                 # executes — a verification failure aborts with no partial
                 # state, same contract as the planning fall-off above
-                verify_plan(plan, dag)
-                self.stats["verified_plans"] += 1
+                self._verify(plan, dag)
             plans.append(plan)
         n = 0
         for plan in plans:
             n += self._run_program(plan, stack=(members, bucket))
         return n
+
+    @staticmethod
+    def _plan(waves, dag) -> Optional[SchedulePlan]:
+        with span("utp.plan") as sp:
+            plan = plan_schedule(waves, dag)
+            if plan is not None:
+                sp.counts.update(
+                    tasks=len(plan.tasks), groups=plan.n_groups, slots=plan.n_slots
+                )
+        return plan
+
+    def _verify(self, plan: SchedulePlan, dag) -> None:
+        with span("utp.verify", groups=plan.n_groups):
+            verify_plan(plan, dag)
+        self.stats["verified_plans"] += 1
 
     def _stack_grids(
         self,
@@ -459,13 +466,14 @@ class JitWaveExecutor(Executor):
         exact request count."""
         datas = [plan.datas[d] for d in plan.roots_order]
         batch = None
-        if stack is not None:
-            members, batch = stack
-            member_lists = [members[d] for d in plan.roots_order]
-            grids = self._stack_grids(member_lists, plan.blocks, batch)
-            shardings = tuple(None for _ in datas)
-        else:
-            grids, shardings = self._enter_grids(datas, plan.blocks)
+        with span("utp.enter_grid", roots=len(datas)):
+            if stack is not None:
+                members, batch = stack
+                member_lists = [members[d] for d in plan.roots_order]
+                grids = self._stack_grids(member_lists, plan.blocks, batch)
+                shardings = tuple(None for _ in datas)
+            else:
+                grids, shardings = self._enter_grids(datas, plan.blocks)
         out_shardings = (
             shardings if all(s is not None for s in shardings) else None
         )
@@ -476,26 +484,34 @@ class JitWaveExecutor(Executor):
             tuple(str(s) for s in shardings),
         ) + plan.key
         fn = self._fn_cache.get(key)
-        if fn is None:
-            fn = build_program(
-                plan,
-                self.backend,
-                self.donate,
-                out_shardings,
-                batch=batch,
-                wrap=self._wrap_program(),
-            )
-            self._fn_cache[key] = fn
-            self.stats["compiles"] += 1
         idxs = plan.flat_idxs  # built once at plan time, device-resident
-        faults.fire(
-            "executor.launch", batch=batch, n_tasks=len(plan.tasks),
-            replay=False,
-        )
-        faults.fire(
-            "launch.oom", batch=batch, n_tasks=len(plan.tasks), replay=False,
-        )
-        outs = fn(grids, idxs)
+        # a program-cache miss is a ``utp.build``: its first call traces,
+        # lowers and compiles (or loads from the persistent cache), and the
+        # span carries those seconds
+        with span(
+            "utp.launch" if fn is not None else "utp.build",
+            tasks=len(plan.tasks), groups=plan.n_groups,
+        ):
+            if fn is None:
+                fn = build_program(
+                    plan,
+                    self.backend,
+                    self.donate,
+                    out_shardings,
+                    batch=batch,
+                    wrap=self._wrap_program(),
+                )
+                self._fn_cache[key] = fn
+                self.stats["compiles"] += 1
+            faults.fire(
+                "executor.launch", batch=batch, n_tasks=len(plan.tasks),
+                replay=False,
+            )
+            faults.fire(
+                "launch.oom", batch=batch, n_tasks=len(plan.tasks),
+                replay=False,
+            )
+            outs = fn(grids, idxs)
         outs = faults.corrupt(
             "executor.output", outs, batch=batch, replay=False
         )

@@ -432,77 +432,84 @@ def build_program(
         else:
             kind = "gather"
             fn = g.op.batched_leaf_fn(backend)
-        steps.append((kind, fn, g.segments, g.write_pos, g.size, base))
+        steps.append((g.op.name, kind, fn, g.segments, g.write_pos, g.size, base))
         base += len(g.arg_slots) * g.size
 
     def program(grids: Tuple[jnp.ndarray, ...], idxs: jnp.ndarray):
         grids = list(grids)
-        for kind, fn, segments, write_pos, size, b0 in steps:
-            # static-offset slices of the single flat index array (trace
-            # order matches SchedulePlan.flat_idxs)
-            n_args = len(segments[0][0])
-            gidx = [
-                idxs[b0 + a * size : b0 + (a + 1) * size]
-                for a in range(n_args)
-            ]
-            if kind == "fused":
-                slots_ = segments[0][0]
-                wslot = slots_[write_pos[0]]
-                grids[wslot] = fn(gidx, tuple(grids[s] for s in slots_))
-                continue
-            blocks = []
-            for a in range(n_args):
-                chunks = []
-                off = 0
-                for slots_, ssize in segments:
-                    ix = gidx[a][off : off + ssize]
-                    g = grids[slots_[a]]
-                    if batch is None:
-                        chunks.append(g[ix[:, 0], ix[:, 1]])
-                    else:
-                        chunks.append(g[:, ix[:, 0], ix[:, 1]])
-                    off += ssize
-                stack = (
-                    chunks[0]
-                    if len(chunks) == 1
-                    else jnp.concatenate(chunks, axis=0 if batch is None else 1)
-                )
-                if batch is not None:
-                    # flatten (B, group) into one leaf stack: the batched
-                    # leaf is elementwise over the stack, so lane order only
-                    # has to match the un-flatten below
-                    stack = stack.reshape((batch * size,) + stack.shape[2:])
-                blocks.append(stack)
-            outs = fn(*blocks)
-            if not isinstance(outs, (tuple, list)):
-                outs = (outs,)
-            for out, a in zip(outs, write_pos):
-                if batch is not None:
-                    out = out.reshape((batch, size) + out.shape[1:])
-                off = 0
-                for slots_, ssize in segments:
-                    r = slots_[a]
-                    ix = gidx[a][off : off + ssize]
-                    if batch is None:
-                        part = (
-                            out
-                            if len(segments) == 1
-                            else out[off : off + ssize]
-                        )
-                        grids[r] = grids[r].at[ix[:, 0], ix[:, 1]].set(
-                            part.astype(dtypes[r])
-                        )
-                    else:
-                        part = (
-                            out
-                            if len(segments) == 1
-                            else out[:, off : off + ssize]
-                        )
-                        grids[r] = grids[r].at[:, ix[:, 0], ix[:, 1]].set(
-                            part.astype(dtypes[r])
-                        )
-                    off += ssize
+        for op, kind, fn, segments, write_pos, size, b0 in steps:
+            # each group's ops carry its operation in their ``op_name``
+            # metadata (``jit(program)/trsm/...``): metadata only, the
+            # compiled code is the same
+            with jax.named_scope(op):
+                _trace_group(grids, idxs, kind, fn, segments, write_pos, size, b0)
         return tuple(grids)
+
+    def _trace_group(grids, idxs, kind, fn, segments, write_pos, size, b0):
+        # static-offset slices of the single flat index array (trace
+        # order matches SchedulePlan.flat_idxs)
+        n_args = len(segments[0][0])
+        gidx = [
+            idxs[b0 + a * size : b0 + (a + 1) * size]
+            for a in range(n_args)
+        ]
+        if kind == "fused":
+            slots_ = segments[0][0]
+            wslot = slots_[write_pos[0]]
+            grids[wslot] = fn(gidx, tuple(grids[s] for s in slots_))
+            return
+        blocks = []
+        for a in range(n_args):
+            chunks = []
+            off = 0
+            for slots_, ssize in segments:
+                ix = gidx[a][off : off + ssize]
+                g = grids[slots_[a]]
+                if batch is None:
+                    chunks.append(g[ix[:, 0], ix[:, 1]])
+                else:
+                    chunks.append(g[:, ix[:, 0], ix[:, 1]])
+                off += ssize
+            stack = (
+                chunks[0]
+                if len(chunks) == 1
+                else jnp.concatenate(chunks, axis=0 if batch is None else 1)
+            )
+            if batch is not None:
+                # flatten (B, group) into one leaf stack: the batched
+                # leaf is elementwise over the stack, so lane order only
+                # has to match the un-flatten below
+                stack = stack.reshape((batch * size,) + stack.shape[2:])
+            blocks.append(stack)
+        outs = fn(*blocks)
+        if not isinstance(outs, (tuple, list)):
+            outs = (outs,)
+        for out, a in zip(outs, write_pos):
+            if batch is not None:
+                out = out.reshape((batch, size) + out.shape[1:])
+            off = 0
+            for slots_, ssize in segments:
+                r = slots_[a]
+                ix = gidx[a][off : off + ssize]
+                if batch is None:
+                    part = (
+                        out
+                        if len(segments) == 1
+                        else out[off : off + ssize]
+                    )
+                    grids[r] = grids[r].at[ix[:, 0], ix[:, 1]].set(
+                        part.astype(dtypes[r])
+                    )
+                else:
+                    part = (
+                        out
+                        if len(segments) == 1
+                        else out[:, off : off + ssize]
+                    )
+                    grids[r] = grids[r].at[:, ix[:, 0], ix[:, 1]].set(
+                        part.astype(dtypes[r])
+                    )
+                off += ssize
 
     jit_kwargs = {}
     if out_shardings is not None:

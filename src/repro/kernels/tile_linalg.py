@@ -236,6 +236,7 @@ def batched_potrf(a: jnp.ndarray, *, interpret: Optional[bool] = None) -> jnp.nd
         in_specs=[_tile_spec(b)],
         out_specs=_tile_spec(b),
         out_shape=jax.ShapeDtypeStruct((n, b, b), a.dtype),
+        name="potrf",
         interpret=_resolve(interpret),
     )(a)
 
@@ -258,6 +259,7 @@ def batched_trsm(
         in_specs=[_tile_spec(nb), _tile_spec(nb)],
         out_specs=_tile_spec(nb),
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
+        name="trsm",
         interpret=_resolve(interpret),
     )(l, b)
 
@@ -280,6 +282,7 @@ def batched_syrk(
         in_specs=[_tile_spec(b), _tile_spec(b)],
         out_specs=_tile_spec(b),
         out_shape=jax.ShapeDtypeStruct(c.shape, c.dtype),
+        name="syrk",
         interpret=_resolve(interpret),
     )(a, c)
 
@@ -299,6 +302,7 @@ def batched_gemm(
         in_specs=[_tile_spec(nb), _tile_spec(nb), _tile_spec(nb)],
         out_specs=_tile_spec(nb),
         out_shape=jax.ShapeDtypeStruct(c.shape, c.dtype),
+        name="gemm",
         interpret=_resolve(interpret),
     )(a, b, c)
 
@@ -320,6 +324,7 @@ def batched_getrf(a: jnp.ndarray, *, interpret: Optional[bool] = None) -> jnp.nd
         in_specs=[_tile_spec(b)],
         out_specs=_tile_spec(b),
         out_shape=jax.ShapeDtypeStruct((n, b, b), a.dtype),
+        name="getrf",
         interpret=_resolve(interpret),
     )(a)
 
@@ -340,6 +345,7 @@ def batched_trsml(
         in_specs=[_tile_spec(nb), _stack_spec(b.shape)],
         out_specs=_stack_spec(b.shape),
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
+        name="trsml",
         interpret=_resolve(interpret),
     )(l, b)
 
@@ -359,6 +365,7 @@ def batched_trsmu(
         in_specs=[_tile_spec(nb), _stack_spec(b.shape)],
         out_specs=_stack_spec(b.shape),
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
+        name="trsmu",
         interpret=_resolve(interpret),
     )(u, b)
 
@@ -378,6 +385,7 @@ def batched_trsmul(
         in_specs=[_tile_spec(nb), _stack_spec(b.shape)],
         out_specs=_stack_spec(b.shape),
         out_shape=jax.ShapeDtypeStruct(b.shape, b.dtype),
+        name="trsmul",
         interpret=_resolve(interpret),
     )(u, b)
 
@@ -397,6 +405,7 @@ def batched_gemmnn(
         in_specs=[_stack_spec(a.shape), _stack_spec(b.shape), _stack_spec(c.shape)],
         out_specs=_stack_spec(c.shape),
         out_shape=jax.ShapeDtypeStruct(c.shape, c.dtype),
+        name="gemmnn",
         interpret=_resolve(interpret),
     )(a, b, c)
 
@@ -414,12 +423,13 @@ def batched_gemmnn(
 # write blocks are guaranteed, but duplicated trailing indices would re-read
 # their own scatter for read-write operations.
 # --------------------------------------------------------------------------
-def make_grid_fused(tile_fn, arity: int, write_arg: int):
+def make_grid_fused(tile_fn, arity: int, write_arg: int, name: str):
     """Build a fused gather/compute/scatter entry point for ``tile_fn``.
 
     ``tile_fn(*tiles) -> tile`` is the pure per-tile body; ``write_arg`` is
     the argument whose grid receives the result (and whose blocks the output
-    aliases).  Returns ``call(idxs, grids, *, interpret=None) -> new grid``.
+    aliases); ``name`` is the kernel's name on the device (the operation's).
+    Returns ``call(idxs, grids, *, interpret=None) -> new grid``.
 
     ``call`` accepts either resident single-workload grids
     ``(nr, nc, br, bc)`` or *stacked* grids ``(B, nr, nc, br, bc)`` holding B
@@ -488,21 +498,22 @@ def make_grid_fused(tile_fn, arity: int, write_arg: int):
             grid_spec=spec,
             out_shape=jax.ShapeDtypeStruct(wg.shape, wg.dtype),
             input_output_aliases={arity + write_arg: 0},
+            name=name,
             interpret=_resolve(interpret),
         )(*(ix.reshape(-1) for ix in idxs), *grids)
 
     return call
 
 
-grid_potrf = make_grid_fused(_potrf_tile, arity=1, write_arg=0)
-grid_trsm = make_grid_fused(_trsm_tile, arity=2, write_arg=1)
-grid_syrk = make_grid_fused(_syrk_tile, arity=2, write_arg=1)
-grid_gemm = make_grid_fused(_gemm_tile, arity=3, write_arg=2)
-grid_getrf = make_grid_fused(_getrf_tile, arity=1, write_arg=0)
-grid_trsml = make_grid_fused(_trsml_tile, arity=2, write_arg=1)
-grid_trsmu = make_grid_fused(_trsmu_tile, arity=2, write_arg=1)
-grid_trsmul = make_grid_fused(_trsmul_tile, arity=2, write_arg=1)
-grid_gemmnn = make_grid_fused(_gemmnn_tile, arity=3, write_arg=2)
+grid_potrf = make_grid_fused(_potrf_tile, arity=1, write_arg=0, name="potrf")
+grid_trsm = make_grid_fused(_trsm_tile, arity=2, write_arg=1, name="trsm")
+grid_syrk = make_grid_fused(_syrk_tile, arity=2, write_arg=1, name="syrk")
+grid_gemm = make_grid_fused(_gemm_tile, arity=3, write_arg=2, name="gemm")
+grid_getrf = make_grid_fused(_getrf_tile, arity=1, write_arg=0, name="getrf")
+grid_trsml = make_grid_fused(_trsml_tile, arity=2, write_arg=1, name="trsml")
+grid_trsmu = make_grid_fused(_trsmu_tile, arity=2, write_arg=1, name="trsmu")
+grid_trsmul = make_grid_fused(_trsmul_tile, arity=2, write_arg=1, name="trsmul")
+grid_gemmnn = make_grid_fused(_gemmnn_tile, arity=3, write_arg=2, name="gemmnn")
 
 # op name -> (fused call, write_arg); consumed by the WaveProgram compiler
 # when the backend is 'pallas' and the group writes exactly that argument.

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from ..core import Dispatcher, GData, GTask
 from ..core.data import from_grid
+from ..core.tracing import span
 from .ops import POTRF
 
 
@@ -40,6 +41,7 @@ def run_cholesky(
     A = GData(a.shape, partitions=partitions, dtype=a.dtype, value=jnp.asarray(a))
     utp_cholesky(d, A)
     d.run()
-    if A.in_grid_epoch:
-        return _tril_grid(A.grid)
-    return jnp.tril(A.value)
+    with span("utp.degrid"):
+        if A.in_grid_epoch:
+            return _tril_grid(A.grid)
+        return jnp.tril(A.value)

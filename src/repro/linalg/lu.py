@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from ..core import Dispatcher, GData, GTask
 from ..core.data import from_grid
+from ..core.tracing import span
 from ..errors import NumericalError
 from .ops import GETRF, LUSOLVE, TRSML, TRSMU, TRSMUL
 
@@ -77,9 +78,10 @@ def _unpack_lu_grid(grid: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 
 def _unpack(A: GData) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    if A.in_grid_epoch:
-        return _unpack_lu_grid(A.grid)
-    return _unpack_lu(A.value)
+    with span("utp.degrid"):
+        if A.in_grid_epoch:
+            return _unpack_lu_grid(A.grid)
+        return _unpack_lu(A.value)
 
 
 def utp_solve(
