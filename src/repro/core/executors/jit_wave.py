@@ -44,7 +44,12 @@ from ..task import GTask, TaskState
 from ..tracing import span
 from ..versioning import InFlightEpoch
 from .base import Executor, group_wave
-from .wave_program import SchedulePlan, build_program, plan_schedule
+from .wave_program import (
+    SchedulePlan,
+    build_program,
+    plan_schedule,
+    shared_grid_groups,
+)
 
 # process-global compiled-program cache: keys are purely structural (op
 # names, backend, shapes, dtypes, shardings, schedule structure) so every
@@ -488,10 +493,10 @@ class JitWaveExecutor(Executor):
         # a program-cache miss is a ``utp.build``: its first call traces,
         # lowers and compiles (or loads from the persistent cache), and the
         # span carries those seconds
-        with span(
-            "utp.launch" if fn is not None else "utp.build",
-            tasks=len(plan.tasks), groups=plan.n_groups,
-        ):
+        counts = {"tasks": len(plan.tasks), "groups": plan.n_groups}
+        if fn is None:
+            counts["shared_grid_groups"] = shared_grid_groups(plan, self.backend)
+        with span("utp.launch" if fn is not None else "utp.build", **counts):
             if fn is None:
                 fn = build_program(
                     plan,

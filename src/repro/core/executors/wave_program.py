@@ -371,6 +371,27 @@ def plan_schedule(
     )
 
 
+def _fused_call(g: GroupPlan, backend: str):
+    """The operation's fused grid kernel for group ``g``, or None (gather
+    path): only single-segment groups that write exactly the kernel's write
+    argument take it."""
+    fused = g.op.grid_fused_fn(backend)
+    if fused is not None and len(g.segments) == 1 and g.write_pos == (fused[1],):
+        return fused[0]
+    return None
+
+
+def shared_grid_groups(plan: SchedulePlan, backend: str) -> int:
+    """Fused groups of ``plan`` whose arguments share a grid, which the
+    program hands to the kernel once (``utp.build``'s count)."""
+    return sum(
+        1
+        for g in plan.groups()
+        if _fused_call(g, backend) is not None
+        and len(set(g.arg_slots)) < len(g.arg_slots)
+    )
+
+
 def build_program(
     plan: SchedulePlan,
     backend: str,
@@ -422,13 +443,9 @@ def build_program(
     base = 0
     for g in plan.groups():
         faults.fire("leaf.fn", op=g.op.name, backend=backend)
-        fused = g.op.grid_fused_fn(backend)
-        if (
-            fused is not None
-            and len(g.segments) == 1
-            and g.write_pos == (fused[1],)
-        ):
-            kind, fn = "fused", fused[0]
+        fn = _fused_call(g, backend)
+        if fn is not None:
+            kind = "fused"
         else:
             kind = "gather"
             fn = g.op.batched_leaf_fn(backend)
@@ -454,9 +471,14 @@ def build_program(
             for a in range(n_args)
         ]
         if kind == "fused":
+            # each distinct grid is one operand of the kernel: a grid passed
+            # twice, once aliased to the output, would be copied by XLA
             slots_ = segments[0][0]
-            wslot = slots_[write_pos[0]]
-            grids[wslot] = fn(gidx, tuple(grids[s] for s in slots_))
+            distinct = list(dict.fromkeys(slots_))
+            arg_grid = tuple(distinct.index(s) for s in slots_)
+            grids[slots_[write_pos[0]]] = fn(
+                gidx, tuple(grids[s] for s in distinct), arg_grid
+            )
             return
         blocks = []
         for a in range(n_args):

@@ -93,9 +93,10 @@ class Operation:
     def grid_fused_fn(self, backend: str):
         """Optional fused gather/compute/scatter kernel over resident grids.
 
-        Returns ``(call, write_arg)`` where ``call(idxs, grids)`` consumes
-        scalar-prefetched ``(n, 2)`` block-index arrays plus one grid per
-        argument and returns the updated grid of ``write_arg`` — or ``None``
+        Returns ``(call, write_arg)`` where ``call(idxs, grids, arg_grid)``
+        consumes scalar-prefetched ``(n, 2)`` block-index arrays plus the
+        group's distinct grids, ``arg_grid[a]`` naming the one argument
+        ``a`` reads, and returns the updated grid of ``write_arg`` — or ``None``
         when the backend has no fused path (the WaveProgram compiler then
         falls back to gather -> batched leaf -> scatter; DESIGN.md §2).
         """

@@ -415,92 +415,184 @@ def batched_gemmnn(
 #
 # Gather -> compute -> scatter in ONE kernel over the resident
 # ``(nr, nc, br, bc)`` grid: per-task block coordinates arrive as
-# scalar-prefetched int32 arrays (``(n, 2)`` flattened), the BlockSpec index
-# maps DMA the addressed blocks straight from the grid into VMEM, and the
-# output aliases the written arg's grid so the scatter is in place — no
-# gathered tile stacks ever materialize in HBM.  Callers must pass exact
-# (unpadded) group sizes: tasks in a group are independent, so distinct
-# write blocks are guaranteed, but duplicated trailing indices would re-read
-# their own scatter for read-write operations.
+# scalar-prefetched int32 arrays (``(n, 2)`` flattened), and the output
+# aliases the written arg's grid so the scatter is in place — no gathered
+# tile stacks ever materialize in HBM.
+#
+# Each distinct grid is ONE operand, left in HBM (``pltpu.HBM``); arguments
+# that share a grid (every argument of a Cholesky group is a block of the
+# one matrix) read their tiles from that operand by DMA.  Passing one buffer
+# twice with one of the two aliased to the output forces XLA to copy the
+# whole grid before the call, since the aliased operand is overwritten
+# while the other is still read.  Reads are double-buffered by hand: step
+# i+1's tiles are started before step i's are waited on, and a tile whose
+# block coordinates equal the previous step's is not fetched again.  The
+# output keeps its blocked spec, so the scatter's writeback stays
+# pipelined.  Callers must pass exact (unpadded) group sizes: tasks in a
+# group are independent, so no block a group reads is written by the same
+# group, but duplicated trailing indices would re-read their own scatter
+# for read-write operations.
 # --------------------------------------------------------------------------
+def _dma_readable(tile_shape, dtype, interpret: bool) -> bool:
+    """Whether Mosaic can DMA one ``tile_shape`` tile out of a grid in HBM:
+    it slices HBM only at whole (sublane, lane) tiles of the layout."""
+    sublanes = 8 * 4 // jnp.dtype(dtype).itemsize
+    return interpret or (tile_shape[-2] % sublanes == 0 and tile_shape[-1] % 128 == 0)
+
+
 def make_grid_fused(tile_fn, arity: int, write_arg: int, name: str):
     """Build a fused gather/compute/scatter entry point for ``tile_fn``.
 
     ``tile_fn(*tiles) -> tile`` is the pure per-tile body; ``write_arg`` is
     the argument whose grid receives the result (and whose blocks the output
     aliases); ``name`` is the kernel's name on the device (the operation's).
-    Returns ``call(idxs, grids, *, interpret=None) -> new grid``.
+    Returns ``call(idxs, grids, arg_grid, *, interpret=None) -> new grid``:
+    ``grids`` are the distinct grids and ``arg_grid[a]`` indexes the one
+    argument ``a`` reads.  Each grid is one operand of the kernel; the
+    written one is aliased to the output.
 
     ``call`` accepts either resident single-workload grids
     ``(nr, nc, br, bc)`` or *stacked* grids ``(B, nr, nc, br, bc)`` holding B
     structurally identical workloads (DESIGN.md §7): the stacked form runs
     the same kernel body under a leading batch grid dimension — grid
-    ``(B, n)`` — with the per-lane block-index array shared by every lane,
-    so a batch of B costs one launch and no extra index traffic.
+    ``(B, n)``, steps in ``(b, i)`` order, reads prefetched across lanes —
+    with the per-lane block-index array shared by every lane, so a batch of
+    B costs one launch and no extra index traffic.
     """
 
-    def kernel(*refs):
-        in_refs = refs[arity : 2 * arity]
-        o_ref = refs[2 * arity]
-        out = tile_fn(*(r[0, 0] for r in in_refs))
-        o_ref[0, 0, :, :] = out.astype(o_ref.dtype)
-
-    def kernel_stacked(*refs):
-        in_refs = refs[arity : 2 * arity]
-        o_ref = refs[2 * arity]
-        out = tile_fn(*(r[0, 0, 0] for r in in_refs))
-        o_ref[0, 0, 0, :, :] = out.astype(o_ref.dtype)
-
-    # block coordinates arrive flattened to (2n,) int32: a 2-D (n, 2) SMEM
-    # array pads every row to 128 words, which overflows the 1 MiB SMEM at
-    # group sizes of a few hundred
-    def _imap(a: int):
-        def imap(i, *idx_refs):
-            r = idx_refs[a]
-            return (r[2 * i], r[2 * i + 1], 0, 0)
-
-        return imap
-
-    def _imap_stacked(a: int):
-        def imap(b, i, *idx_refs):
-            r = idx_refs[a]
-            return (b, r[2 * i], r[2 * i + 1], 0, 0)
-
-        return imap
-
-    def call(idxs, grids, *, interpret: Optional[bool] = None):
-        assert len(idxs) == arity and len(grids) == arity
-        n = idxs[0].shape[0]
+    def call(idxs, grids, arg_grid, *, interpret: Optional[bool] = None):
         from jax.experimental.pallas import tpu as pltpu
 
-        stacked = grids[write_arg].ndim == 5
-        if stacked:
-            grid = (grids[write_arg].shape[0], n)
-            body, imap_of, lead = kernel_stacked, _imap_stacked, (1, 1, 1)
-        else:
-            grid = (n,)
-            body, imap_of, lead = kernel, _imap, (1, 1)
-        in_specs = [
-            pl.BlockSpec(lead + grids[a].shape[-2:], imap_of(a))
-            for a in range(arity)
-        ]
+        interp = _resolve(interpret)
+        assert len(idxs) == arity == len(arg_grid)
+        assert sorted(set(arg_grid)) == list(range(len(grids)))
+        wg = grids[arg_grid[write_arg]]
+        stacked = wg.ndim == 5
+        grid = (wg.shape[0], idxs[0].shape[0]) if stacked else (idxs[0].shape[0],)
+        lead = (1,) * len(grid) + (1,)
+
+        # block coordinates arrive flattened to (2n,) int32: a 2-D (n, 2) SMEM
+        # array pads every row to 128 words, which overflows the 1 MiB SMEM at
+        # group sizes of a few hundred
+        def block_map(a):
+            def imap(*at):
+                r, i = at[len(grid) + a], at[len(grid) - 1]
+                return at[: len(grid) - 1] + (r[2 * i], r[2 * i + 1], 0, 0)
+
+            return imap
+
+        # operands: each grid read by DMA once, in HBM; a grid whose tiles
+        # Mosaic cannot slice there is read by BlockSpec, one operand per
+        # argument (and then copied by XLA when it is also written)
+        operands, in_specs, src, hbm = [], [], [], {}
+        for a, k in enumerate(arg_grid):
+            g = grids[k]
+            if not _dma_readable(g.shape[-2:], g.dtype, interp):
+                src.append(len(operands))
+                in_specs.append(pl.BlockSpec(lead + g.shape[-2:], block_map(a)))
+                operands.append(g)
+                continue
+            if k not in hbm:
+                hbm[k] = len(operands)
+                in_specs.append(pl.BlockSpec(memory_space=pltpu.HBM))
+                operands.append(g)
+            src.append(hbm[k])
+        dma = [a for a in range(arity) if arg_grid[a] in hbm]
+
+        def kernel(*refs):
+            idx = refs[:arity]
+            ops = refs[arity : arity + len(operands)]
+            o_ref = refs[arity + len(operands)]
+            bufs = dict(zip(dma, refs[arity + len(operands) + 1 : -2]))
+            sem, cur = refs[-2:]  # DMA semaphores (2, arity); slot per arg
+            # scalar bookkeeping in lax, not jnp operators: the kernel is
+            # traced anew for every group, and each jnp operator traces a
+            # wrapper of its own
+            add, eq, sel, not_ = lax.add, lax.eq, lax.select, lax.bitwise_not
+            i, steps = pl.program_id(len(grid) - 1), pl.num_programs(len(grid) - 1)
+            last = eq(i, steps - 1)
+            nxt = sel(last, 0, add(i, 1))  # step i+1, or step 0 of lane+1
+            prv = lax.max(add(i, -1), 0)
+            if stacked:
+                lane, lanes = pl.program_id(0), pl.num_programs(0)
+                first = eq(add(lane, i), 0)
+                more = not_(lax.bitwise_and(last, eq(lane, lanes - 1)))
+                nlane = sel(last, lax.min(add(lane, 1), lanes - 1), lane)
+            else:
+                lane = nlane = None
+                first, more = eq(i, 0), not_(last)
+
+            def copy(a, ln, at, slot):
+                tile = ops[src[a]].at[at if ln is None else (ln,) + at]
+                return pltpu.make_async_copy(tile, bufs[a].at[slot], sem.at[slot, a])
+
+            def same(u, v):
+                return lax.bitwise_and(eq(u[0], v[0]), eq(u[1], v[1]))
+
+            # block coordinates of the previous, this and the next step
+            rows = [lax.mul(t, 2) for t in (prv, i, nxt)]
+            cols = [add(r, 1) for r in rows]
+            pos = {a: [(idx[a][r], idx[a][c]) for r, c in zip(rows, cols)] for a in dma}
+
+            @pl.when(first)
+            def _():
+                for a in dma:
+                    copy(a, lane, pos[a][1], 0).start()
+                    cur[a] = 0
+
+            slot, at, fetched = {}, {}, {}
+            for a in dma:
+                p_, at[a], n_ = pos[a]
+                # start step i+1's read into the other slot, unless the
+                # tile is the one already held
+                c = slot[a] = cur[a]
+                keep = lax.bitwise_and(not_(last), same(at[a], n_))
+                cur[a] = sel(keep, c, lax.sub(1, c))
+
+                @pl.when(lax.bitwise_and(more, not_(keep)))
+                def _():
+                    copy(a, nlane, n_, lax.sub(1, c)).start()
+
+                fetched[a] = lax.bitwise_or(eq(i, 0), not_(same(p_, at[a])))
+
+            # then wait for step i's own reads
+            for a, c in slot.items():
+
+                @pl.when(fetched[a])
+                def _():
+                    copy(a, lane, at[a], c).wait()
+
+            out = tile_fn(
+                *(
+                    bufs[a][slot[a]] if a in slot else ops[src[a]][(0,) * len(lead)]
+                    for a in range(arity)
+                )
+            )
+            o_ref[(0,) * len(lead)] = out.astype(o_ref.dtype)
+
         spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=arity,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                lead + grids[write_arg].shape[-2:], imap_of(write_arg)
-            ),
+            out_specs=pl.BlockSpec(lead + wg.shape[-2:], block_map(write_arg)),
+            scratch_shapes=[
+                pltpu.VMEM((2,) + g.shape[-2:], g.dtype)
+                for g in (grids[arg_grid[a]] for a in dma)
+            ]
+            + [pltpu.SemaphoreType.DMA((2, arity)), pltpu.SMEM((arity,), jnp.int32)],
         )
-        wg = grids[write_arg]
         return pl.pallas_call(
-            body,
+            kernel,
             grid_spec=spec,
             out_shape=jax.ShapeDtypeStruct(wg.shape, wg.dtype),
-            input_output_aliases={arity + write_arg: 0},
+            input_output_aliases={arity + src[write_arg]: 0},
+            # steps carry the read buffers from one to the next
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",) * len(grid)
+            ),
             name=name,
-            interpret=_resolve(interpret),
-        )(*(ix.reshape(-1) for ix in idxs), *grids)
+            interpret=interp,
+        )(*(ix.reshape(-1) for ix in idxs), *operands)
 
     return call
 

@@ -6,11 +6,15 @@ limits; these compiles do.  Every ``GRID_FUSED`` kernel and every
 (resident and stacked grid forms, plus the rectangular and vector
 right-hand sides of the solve drains), and one g4-style fused group is
 compiled on a 2x2 mesh inside ``ShardExecutor``'s ``shard_map`` wrapper.
+A g2p Cholesky drain program and a stacked gemm group are compiled to show
+that a grid the arguments share reaches each kernel once, uncopied.
 
 The topology is described only inside the module-scoped fixtures below:
 loading the TPU compiler takes a process-wide lock, so it must happen in
 the one worker that runs this file, never at import or collection.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,8 +22,10 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.compat import make_mesh
-from repro.core.executors import ShardExecutor
+from repro.core import spd_matrix
+from repro.core.executors import ShardExecutor, clear_compile_cache, drain_memo_records
 from repro.kernels import tile_linalg as tl
+from repro.linalg import run_cholesky
 
 TILES = [128, 256, 512]
 ARITY = {
@@ -69,8 +75,11 @@ def _compile(fn, *args) -> str:
 
 
 def _grid_call(op):
+    """The fused kernel with one grid per argument."""
     fn, _ = tl.GRID_FUSED[op]
-    return lambda idxs, grids: fn(idxs, grids, interpret=False)
+    return lambda idxs, grids: fn(
+        idxs, grids, tuple(range(len(grids))), interpret=False
+    )
 
 
 @pytest.mark.parametrize("b", TILES)
@@ -127,3 +136,52 @@ def test_g4_sharded_group_compiles(topo):
     text = program.lower(grids, idxs).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text  # the replicated-compute cost, made explicit
+
+
+def _assert_grid_passed_once(text: str, grid: str, kernels: int) -> None:
+    """No copy of the ``grid``-typed buffer, and each of the ``kernels``
+    Mosaic calls takes one operand of that type."""
+    lines = text.splitlines()
+    copies = [
+        ln for ln in lines
+        if re.search(r"\scopy(-start)?\(", ln) and grid in ln.split(" copy")[0]
+    ]
+    assert copies == []
+    calls = [ln for ln in lines if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == kernels
+    for ln in calls:
+        operands = re.search(
+            r"operand_layout_constraints=\{(.*?)\}, output_to_operand_aliasing", ln
+        ).group(1)
+        assert operands.count(grid + "{") == 1, ln
+
+
+def test_cholesky_drain_program_passes_its_grid_once(one_chip, monkeypatch):
+    """The g2p drain at n = 2048 in 512-wide tiles: every trsm, syrk and gemm
+    group reads blocks of the one matrix, and none makes XLA copy it."""
+    clear_compile_cache()
+    run_cholesky(spd_matrix(256), graph="g2p", partitions=((4, 4),)).block_until_ready()
+    (rec,) = drain_memo_records()
+    # trace afresh with Mosaic kernels, as on the chip (interpret mode off)
+    monkeypatch.setattr(tl, "default_interpret", lambda: False)
+    jax.clear_caches()
+    try:
+        grid = _struct(one_chip, (4, 4, 512, 512))
+        idxs = _struct(one_chip, rec.idxs.shape, rec.idxs.dtype)
+        text = rec.fn.lower((grid,), idxs).compile().as_text()
+    finally:
+        # drop the Mosaic trace, so no later CPU call of the program finds it
+        clear_compile_cache()
+        jax.clear_caches()
+    # 4 potrf + 3 trsm + 3 syrk + 2 gemm groups
+    _assert_grid_passed_once(text, "f32[4,4,512,512]", kernels=12)
+
+
+def test_stacked_gemm_group_passes_its_grid_once(one_chip):
+    def group(idxs, grid):
+        return tl.grid_gemm(idxs, (grid,), (0, 0, 0), interpret=False)
+
+    idxs = [_struct(one_chip, (3, 2), jnp.int32)] * 3
+    grid = _struct(one_chip, (2, 4, 4, 512, 512))
+    text = jax.jit(group, donate_argnums=(1,)).lower(idxs, grid).compile().as_text()
+    _assert_grid_passed_once(text, "f32[2,4,4,512,512]", kernels=1)

@@ -122,6 +122,19 @@ def test_repeated_cholesky_replays_with_a_fixed_set_of_spans(ring):
         assert recs[1][6] == {"tasks": 20, "groups": plan[6]["groups"]}
 
 
+@pytest.mark.parametrize("graph,p", [("g2p", 3), ("g2p", 4), ("g2", 4)])
+def test_build_counts_the_groups_that_share_a_grid(ring, graph, p):
+    """A Cholesky drain's trsm, syrk and gemm groups read blocks of the one
+    matrix: 2p - 2 + (p - 2) fused groups on g2p, each handed the grid once;
+    none on g2, whose groups gather their tiles."""
+    clear_compile_cache()
+    a = spd_matrix(16 * p)
+    run_cholesky(a, graph=graph, partitions=((p, p),)).block_until_ready()
+    (build,) = [r for r in tracing.records() if r[3] == "utp.build"]
+    want = 2 * p - 2 + (p - 2) if graph == "g2p" else 0
+    assert build[6]["shared_grid_groups"] == want
+
+
 def test_g2p_program_lowered_for_tpu_names_each_tile_kernel(monkeypatch):
     clear_compile_cache()
     run_cholesky(spd_matrix(256), graph="g2p", partitions=((4, 4),)).block_until_ready()
