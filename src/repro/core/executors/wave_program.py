@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...kernels.tile_linalg import _dma_readable
 from ...testing import faults
 from ..data import GData
 from ..task import GTask
@@ -389,6 +390,22 @@ def shared_grid_groups(plan: SchedulePlan, backend: str) -> int:
         for g in plan.groups()
         if _fused_call(g, backend) is not None
         and len(set(g.arg_slots)) < len(g.arg_slots)
+    )
+
+
+def blockspec_groups(plan: SchedulePlan, backend: str) -> int:
+    """Fused groups of ``plan`` that read at least one grid by BlockSpec, one
+    operand per argument, because its tile is not whole (sublane, lane)
+    layout tiles and so cannot be sliced by DMA (``utp.build``'s count).
+    Counted by the chip's rule, so a CPU run counts what the TPU would."""
+    thin = [
+        not _dma_readable(plan.blocks[s], plan.datas[d].dtype, interpret=False)
+        for s, d in enumerate(plan.roots_order)
+    ]
+    return sum(
+        1
+        for g in plan.groups()
+        if _fused_call(g, backend) is not None and any(thin[s] for s in g.arg_slots)
     )
 
 

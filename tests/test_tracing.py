@@ -6,10 +6,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import spd_matrix, tracing
+from repro.core import dd_matrix, spd_matrix, tracing
 from repro.core.executors import clear_compile_cache, drain_memo_records
 from repro.kernels import tile_linalg
-from repro.linalg import run_cholesky
+from repro.linalg import run_cholesky, run_lu_solve
 
 
 @pytest.fixture
@@ -133,6 +133,29 @@ def test_build_counts_the_groups_that_share_a_grid(ring, graph, p):
     (build,) = [r for r in tracing.records() if r[3] == "utp.build"]
     want = 2 * p - 2 + (p - 2) if graph == "g2p" else 0
     assert build[6]["shared_grid_groups"] == want
+
+
+@pytest.mark.parametrize("op", ["lu_solve", "cholesky"])
+def test_build_counts_the_groups_read_by_blockspec(ring, op):
+    """A fused group reads a grid by BlockSpec when the grid's tile is not
+    whole (8, 128) layout tiles, counted by the chip's rule on any backend.
+    With 128-wide tiles only a vector right-hand side is that thin: an
+    LU-solve at p = 4 reads it in its p + p forward and backward diagonal
+    solves, its p - 1 forward updates (one group a step, beside the
+    factorization) and its p (p - 1) / 2 backward updates (row by row, each
+    row's updates a chain); a Cholesky drain reads none."""
+    p = 4
+    n = 128 * p
+    clear_compile_cache()
+    if op == "lu_solve":
+        run_lu_solve(dd_matrix(n), jnp.ones((n,), jnp.float32), graph="g2p",
+                     partitions=((p, p),)).block_until_ready()
+        want = 2 * p + (p - 1) + p * (p - 1) // 2
+    else:
+        run_cholesky(spd_matrix(n), graph="g2p", partitions=((p, p),)).block_until_ready()
+        want = 0
+    (build,) = [r for r in tracing.records() if r[3] == "utp.build"]
+    assert build[6]["blockspec_groups"] == want
 
 
 def test_g2p_program_lowered_for_tpu_names_each_tile_kernel(monkeypatch):
