@@ -46,6 +46,7 @@ from ..versioning import InFlightEpoch
 from .base import Executor, group_wave
 from .wave_program import (
     SchedulePlan,
+    blocked_panel_groups,
     blockspec_groups,
     build_program,
     plan_schedule,
@@ -498,6 +499,7 @@ class JitWaveExecutor(Executor):
         if fn is None:
             counts["shared_grid_groups"] = shared_grid_groups(plan, self.backend)
             counts["blockspec_groups"] = blockspec_groups(plan, self.backend)
+            counts["blocked_panel_groups"] = blocked_panel_groups(plan, self.backend)
         with span("utp.launch" if fn is not None else "utp.build", **counts):
             if fn is None:
                 fn = build_program(

@@ -76,7 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...kernels.tile_linalg import _dma_readable
+from ...kernels.tile_linalg import PANEL_SOLVES, _dma_readable, _panel_strip
 from ...testing import faults
 from ..data import GData
 from ..task import GTask
@@ -406,6 +406,20 @@ def blockspec_groups(plan: SchedulePlan, backend: str) -> int:
         1
         for g in plan.groups()
         if _fused_call(g, backend) is not None and any(thin[s] for s in g.arg_slots)
+    )
+
+
+def blocked_panel_groups(plan: SchedulePlan, backend: str) -> int:
+    """Fused panel-solve groups of ``plan`` whose triangular tile (argument
+    0) is solved in diagonal strips with MXU dots between them, not by one
+    whole-tile recurrence (``utp.build``'s count)."""
+    order = [blk[-1] for blk in plan.blocks]
+    return sum(
+        1
+        for g in plan.groups()
+        if g.op.name in PANEL_SOLVES
+        and _fused_call(g, backend) is not None
+        and _panel_strip(order[g.arg_slots[0]]) < order[g.arg_slots[0]]
     )
 
 

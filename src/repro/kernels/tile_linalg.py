@@ -9,9 +9,12 @@ TPU adaptation notes:
   - tiles live in VMEM via explicit ``BlockSpec``s; ``b`` should be a
     multiple of 128 so the MXU sees aligned matmuls (tests sweep smaller
     shapes in interpret mode where alignment is not enforced);
-  - POTRF/TRSM are column-recurrences (O(b) steps of rank-1/matvec work on
-    the VPU); they are only ever applied to the O(p) diagonal/panel tiles
-    while the O(p^3) trailing updates (SYRK/GEMM) are single MXU matmuls.
+  - POTRF/GETRF are column-recurrences (O(b) steps of rank-1 work on the
+    VPU); the panel solves (TRSM/TRSML/TRSMU/TRSMUL) are row recurrences
+    run only on 128-row diagonal strips, each strip first taking the
+    already-solved rows as one MXU matmul.  All of them are only ever
+    applied to the O(p) diagonal/panel tiles while the O(p^3) trailing
+    updates (SYRK/GEMM) are single MXU matmuls.
 """
 
 from __future__ import annotations
@@ -49,8 +52,15 @@ def _stack_spec(shape):
 # The panel bodies are written for Mosaic: every value is 2-D, and a row or
 # column at the loop index is selected with a broadcasted-iota mask and a
 # reduction, never by dynamic slicing or indexed update of a traced value
-# (Mosaic cannot lower ``dynamic_slice`` on values).  Each step is a masked
-# rank-1 update of the whole tile on the VPU.
+# (Mosaic cannot lower ``dynamic_slice`` on values).  Each step of
+# ``_potrf_tile`` and ``_getrf_tile`` is a masked rank-1 update of the whole
+# tile on the VPU.  The panel solves are blocked: the right-hand side is cut
+# into strips of ``_panel_strip(nb)`` rows by static slices; each strip
+# first takes everything already solved as one MXU dot, then runs a row
+# recurrence with only the strip's diagonal block of the triangular
+# factor.  The right-side solves (TRSM, TRSMU) run on the transposes, so no
+# step reduces across lanes.  Off-diagonal blocks of a packed L\U tile are
+# pure L or pure U, so only the diagonal block needs the recurrence's mask.
 # --------------------------------------------------------------------------
 def _iota(shape, dim):
     return lax.broadcasted_iota(jnp.int32, shape, dim)
@@ -96,24 +106,77 @@ def _potrf_tile(a: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(rows >= cols, m, 0.0)
 
 
-def _trsm_tile(L: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
-    """X = B @ inv(L)^T, L lower non-unit.
+# f32 operands through the MXU at full precision (Mosaic's default for a
+# dot is bf16 passes)
+_HIGHEST = lax.Precision.HIGHEST
 
-    Column recurrence: X[:, j] = (B[:, j] - X @ L[j]) / L[j, j]; columns
-    >= j of X are still zero, so L's upper triangle multiplies zeros.
+
+def _dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    return jnp.dot(a, b, preferred_element_type=jnp.float32, precision=_HIGHEST)
+
+
+# the panel solves whose tile bodies run in ``_panel_strip`` strips
+PANEL_SOLVES = ("trsm", "trsml", "trsmu", "trsmul")
+
+
+def _panel_strip(nb: int) -> int:
+    """Width of the diagonal strips a panel solve of a triangular tile of
+    order ``nb`` runs its recurrence on: the lane width where the tile holds
+    several whole lane-wide strips, else the whole tile (one strip, no
+    dots)."""
+    return 128 if nb % 128 == 0 and nb > 128 else nb
+
+
+def _diag(m: jnp.ndarray) -> jnp.ndarray:
+    """Diagonal of square 2-D ``m`` as an (n, 1) array."""
+    n = m.shape[0]
+    return jnp.sum(
+        jnp.where(_iota((n, n), 0) == _iota((n, n), 1), m, 0.0), axis=1, keepdims=True
+    )
+
+
+def _lower_rec(L: jnp.ndarray, B: jnp.ndarray, unit: bool) -> jnp.ndarray:
+    """X = inv(L) @ B by right-looking row recurrence, L lower.
+
+    Step i subtracts L[k, i] * (X[i] / L[i, i]) from every row k > i; rows
+    <= i see a zero multiplier, so the mask is on L's (nb, 1) column, not on
+    the strip.  Row i itself is divided by L[i, i] once, after the loop,
+    which gives the quotient the step used.  Only L's strict lower triangle
+    (and its diagonal unless ``unit``) is read.
     """
-    L = L.astype(jnp.float32)
-    B = B.astype(jnp.float32)
     nb = L.shape[-1]
-    cols = _iota(B.shape, 1)
+    rv = _iota((nb, 1), 0)
 
-    def body(j, X):
-        lj = _row(L, j)  # (1, nb)
-        s = jnp.sum(X * lj, axis=1, keepdims=True)
-        x = (_col(B, j) - s) / _col(lj, j)
-        return jnp.where(cols == j, x, X)
+    def body(i, X):
+        li = _col(L, i)  # (nb, 1)
+        x = _row(X, i) if unit else _row(X, i) / _row(li, i)
+        return X - jnp.where(rv > i, li, 0.0) * x
 
-    return lax.fori_loop(0, nb, body, jnp.zeros_like(B))
+    X = lax.fori_loop(0, nb, body, B)
+    return X if unit else X / _diag(L)
+
+
+def _lower_solve(L: jnp.ndarray, B: jnp.ndarray, unit: bool = False) -> jnp.ndarray:
+    """X = inv(L) @ B, L lower, in row strips of ``_panel_strip`` rows.
+
+    Row strip I: X[I] = B[I] - L[I, :I0] @ X[:I0], solved against L[I, I].
+    """
+    nb = L.shape[-1]
+    w = _panel_strip(nb)
+    xs = []
+    for i0 in range(0, nb, w):
+        I = slice(i0, i0 + w)
+        rhs = B[I]
+        if xs:
+            rhs = rhs - _dot(L[I, :i0], jnp.concatenate(xs, axis=0))
+        xs.append(_lower_rec(L[I, I], rhs, unit))
+    return jnp.concatenate(xs, axis=0)
+
+
+def _trsm_tile(L: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
+    """X = B @ inv(L)^T, L lower non-unit: X^T = inv(L) @ B^T, solved by
+    rows (no lane reduction over the right-hand side)."""
+    return _lower_solve(L.astype(jnp.float32), B.astype(jnp.float32).T).T
 
 
 def _getrf_tile(a: jnp.ndarray) -> jnp.ndarray:
@@ -141,65 +204,56 @@ def _getrf_tile(a: jnp.ndarray) -> jnp.ndarray:
 def _trsml_tile(L: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
     """X = inv(L) @ B with L unit-lower (stored diagonal/upper ignored).
 
-    Right-looking row recurrence: once row i of X is final, subtract
-    L[k, i] * X[i] from every row k > i.  Only L's strict lower triangle is
-    read, so packed L\\U blocks pass unmasked.
+    Only L's strict lower triangle is read, so packed L\\U blocks pass
+    unmasked.
     """
-    L = L.astype(jnp.float32)
-    B = B.astype(jnp.float32)
-    nb = L.shape[-1]
-    rows = _iota(B.shape, 0)
-
-    def body(i, X):
-        return jnp.where(rows > i, X - _col(L, i) * _row(X, i), X)
-
-    return lax.fori_loop(0, nb, body, B)
+    return _lower_solve(L.astype(jnp.float32), B.astype(jnp.float32), unit=True)
 
 
 def _trsmu_tile(U: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
-    """X = B @ inv(U) with U upper non-unit (stored lower junk ignored).
+    """X = B @ inv(U) with U upper non-unit (stored lower junk ignored):
+    X^T = inv(U^T) @ B^T, U^T lower.  Only U's upper triangle is read."""
+    return _lower_solve(U.astype(jnp.float32).T, B.astype(jnp.float32).T).T
 
-    Right-looking column recurrence: X[:, j] = X[:, j] / U[j, j], then
-    subtract X[:, j] * U[j, k] from every column k > j.  Only U's upper
-    triangle is read.
+
+def _trsmul_rec(U: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
+    """X = inv(U) @ B by bottom-up row recurrence, U upper non-unit.
+
+    Step i subtracts U[k, i] * X[i] / U[i, i] from every row k < i, the
+    mask on U's column as in ``_lower_rec``; rows are divided by the
+    diagonal after the loop.  Only U's upper triangle is read.
     """
-    U = U.astype(jnp.float32)
-    B = B.astype(jnp.float32)
     nb = U.shape[-1]
-    cols = _iota(B.shape, 1)
-
-    def body(j, X):
-        uj = _row(U, j)  # (1, nb)
-        x = _col(X, j) / _col(uj, j)
-        return jnp.where(cols > j, X - x * uj, jnp.where(cols == j, x, X))
-
-    return lax.fori_loop(0, nb, body, B)
-
-
-def _trsmul_tile(U: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
-    """X = inv(U) @ B with U upper non-unit (stored lower junk ignored).
-
-    Bottom-up right-looking row recurrence: X[i] = X[i] / U[i, i], then
-    subtract U[k, i] * X[i] from every row k < i.  Only U's upper triangle
-    is read, so packed L\\U blocks pass unmasked.
-    """
-    U = U.astype(jnp.float32)
-    B = B.astype(jnp.float32)
-    nb = U.shape[-1]
-    rows = _iota(B.shape, 0)
+    rv = _iota((nb, 1), 0)
 
     def body(t, X):
         i = nb - 1 - t
         ui = _col(U, i)  # (nb, 1)
         x = _row(X, i) / _row(ui, i)
-        return jnp.where(rows < i, X - ui * x, jnp.where(rows == i, x, X))
+        return X - jnp.where(rv < i, ui, 0.0) * x
 
-    return lax.fori_loop(0, nb, body, B)
+    return lax.fori_loop(0, nb, body, B) / _diag(U)
 
 
-# f32 operands through the MXU at full precision (Mosaic's default for a
-# dot is bf16 passes)
-_HIGHEST = lax.Precision.HIGHEST
+def _trsmul_tile(U: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
+    """X = inv(U) @ B with U upper non-unit (stored lower junk ignored).
+
+    Row strips bottom-up: X[I] = B[I] - U[I, I1:] @ X[I1:], solved against
+    U[I, I].  Only U's upper triangle is read, so packed L\\U blocks pass
+    unmasked.
+    """
+    U = U.astype(jnp.float32)
+    B = B.astype(jnp.float32)
+    nb = U.shape[-1]
+    w = _panel_strip(nb)
+    xs = []  # solved strips, top first
+    for i1 in range(nb, 0, -w):
+        I = slice(i1 - w, i1)
+        rhs = B[I]
+        if xs:
+            rhs = rhs - _dot(U[I, i1:], jnp.concatenate(xs, axis=0))
+        xs.insert(0, _trsmul_rec(U[I, I], rhs))
+    return jnp.concatenate(xs, axis=0)
 
 
 def _gemmnn_tile(a: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:

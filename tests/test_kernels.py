@@ -8,9 +8,12 @@ import pytest
 
 from repro.core.data import dd_matrix, spd_matrix
 from repro.kernels import ops, ref
+from repro.kernels.tile_linalg import _panel_strip
 
 DTYPES = [jnp.float32]
 SIZES = [8, 16, 32]
+# tile orders whose panel solves run in 128-wide strips with MXU dots
+BLOCKED = [256, 512]
 
 
 def rand(key, *shape, dtype=jnp.float32):
@@ -25,7 +28,7 @@ def test_potrf(n, dtype):
     np.testing.assert_allclose(out, ref.potrf(a), rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("n", SIZES)
+@pytest.mark.parametrize("n", SIZES + BLOCKED)
 def test_trsm(n):
     l = ref.potrf(spd_matrix(n, seed=n))
     b = rand(1, n, n)
@@ -58,7 +61,7 @@ def test_getrf(n):
     np.testing.assert_allclose(l @ u, a, rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("n", SIZES)
+@pytest.mark.parametrize("n", SIZES + BLOCKED)
 def test_trsml_trsmu_mask_packed_junk(n):
     """Solve leaves read only their triangle: packed L\\U input is fine."""
     packed = ref.getrf(dd_matrix(n, seed=n))
@@ -77,7 +80,7 @@ def test_trsml_trsmu_mask_packed_junk(n):
     )
 
 
-@pytest.mark.parametrize("n", SIZES)
+@pytest.mark.parametrize("n", SIZES + BLOCKED)
 def test_trsmul(n):
     """Left-upper TRSM (the fourth orientation): x = inv(triu(u)) @ b."""
     u = jnp.triu(dd_matrix(n, seed=n))
@@ -88,10 +91,18 @@ def test_trsmul(n):
     np.testing.assert_allclose(u @ out, b, rtol=2e-3, atol=2e-3)
 
 
-@pytest.mark.parametrize("bc", [1, 8])
-def test_trsmul_nonsquare_rhs(bc):
-    """RHS tiles may be non-square (blocked vector right-hand sides)."""
-    n = 16
+@pytest.mark.parametrize(
+    "n,bc",
+    [
+        pytest.param(16, 1, id="1"),
+        pytest.param(16, 8, id="8"),
+        pytest.param(512, 1, id="512-1"),
+        pytest.param(512, 8, id="512-8"),
+    ],
+)
+def test_trsmul_nonsquare_rhs(n, bc):
+    """RHS tiles may be non-square (blocked vector right-hand sides); the
+    strips follow the triangular tile's order, not the right-hand side's."""
     u = jnp.triu(dd_matrix(n, seed=2))
     b = rand(22, n, bc)
     np.testing.assert_allclose(
@@ -102,6 +113,15 @@ def test_trsmul_nonsquare_rhs(bc):
         ops.trsml(u, b, interpret=True), ref.trsml(u, b),
         rtol=2e-3, atol=2e-3,
     )
+
+
+@pytest.mark.parametrize(
+    "nb,want", [(8, 8), (16, 16), (32, 32), (128, 128), (256, 128), (512, 128)]
+)
+def test_panel_strip_follows_the_tile_order(nb, want):
+    """Only tiles of several whole lane-wide strips are blocked; smaller
+    tiles keep the one whole-tile recurrence."""
+    assert _panel_strip(nb) == want
 
 
 @pytest.mark.parametrize("n", [8, 16])

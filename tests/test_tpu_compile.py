@@ -99,8 +99,8 @@ def test_grid_getrf_stacked_compiles(one_chip, b):
 
 @pytest.mark.parametrize(
     "b,width,lanes",
-    [(512, 128, None), (128, 1, 2)],
-    ids=["tile512-rhs128", "tile128-vector-stacked"],
+    [(512, 128, None), (512, 1, None), (128, 1, 2)],
+    ids=["tile512-rhs128", "tile512-vector", "tile128-vector-stacked"],
 )
 @pytest.mark.parametrize("op", ["trsml", "trsmul", "gemmnn"])
 def test_grid_solve_rhs_compiles(one_chip, op, b, width, lanes):

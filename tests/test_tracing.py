@@ -158,6 +158,28 @@ def test_build_counts_the_groups_read_by_blockspec(ring, op):
     assert build[6]["blockspec_groups"] == want
 
 
+@pytest.mark.parametrize(
+    "op,tile,p", [("cholesky", 256, 2), ("lu_solve", 256, 2), ("cholesky", 8, 4)]
+)
+def test_build_counts_the_blocked_panel_groups(ring, op, tile, p):
+    """Every fused panel-solve group is solved in strips when its triangular
+    tile holds several 128-wide strips, and none at smaller tiles: a
+    Cholesky drain has p - 1 trsm groups; an LU-solve with a vector
+    right-hand side has p - 1 trsml and p - 1 trsmu groups on the matrix
+    and p forward and p backward diagonal solves on the right-hand side."""
+    n = tile * p
+    clear_compile_cache()
+    if op == "lu_solve":
+        run_lu_solve(dd_matrix(n), jnp.ones((n,), jnp.float32), graph="g2p",
+                     partitions=((p, p),)).block_until_ready()
+        groups = 4 * p - 2
+    else:
+        run_cholesky(spd_matrix(n), graph="g2p", partitions=((p, p),)).block_until_ready()
+        groups = p - 1
+    (build,) = [r for r in tracing.records() if r[3] == "utp.build"]
+    assert build[6]["blocked_panel_groups"] == (groups if tile > 128 else 0)
+
+
 def test_g2p_program_lowered_for_tpu_names_each_tile_kernel(monkeypatch):
     clear_compile_cache()
     run_cholesky(spd_matrix(256), graph="g2p", partitions=((4, 4),)).block_until_ready()
