@@ -36,7 +36,7 @@ class ArchConfig:
     embed_scale: bool = False  # gemma-style sqrt(d_model) embedding scale
     loss_chunk: int = 512  # chunked cross-entropy sequence-chunk length
     attn_q_chunk: int = 1024  # flash-style query-chunk for the no-cache path
-    # ---- performance knobs (hillclimbed in EXPERIMENTS.md §Perf) ----------
+    # ---- performance knobs ------------------------------------------------
     score_dtype: str = "f32"  # attention score/softmax dtype: 'f32' | 'bf16'
     # Megatron-SP (validated §Perf: qwen3 train mfu_bound +53%, rwkv6 +200%,
     # HBM/chip 133->18 GB): residual stream sharded on seq between blocks.

@@ -29,5 +29,5 @@ CONFIG = ArchConfig(
     optim_state_dtype=jnp.bfloat16,  # 2x HBM saving on m/v at 340B
     # microbatching REFUTED for fit (§Perf): per-microbatch grad reductions
     # scale collective time ~m x; 340B single-pod training runs multi-pod
-    # (FSDP over ("pod","data")) instead — see EXPERIMENTS §Dry-run.
+    # (FSDP over ("pod","data")) instead.
 )

@@ -13,11 +13,10 @@ step program for the production mesh — (16,16)=256 chips single-pod and
 allocation), then extract:
 
     compiled.memory_analysis()   -> fits-in-HBM proof
-    compiled.cost_analysis()     -> FLOPs / bytes for §Roofline
+    compiled.cost_analysis()     -> FLOPs / bytes for the roofline
     compiled.as_text()           -> collective bytes (parsed)
 
-Results land in benchmarks/results/<mesh>/<arch>__<shape>.json and feed
-EXPERIMENTS.md §Dry-run / §Roofline.
+Results land in benchmarks/results/<mesh>/<arch>__<shape>.json.
 
 Usage:
     python -m repro.launch.dryrun --arch qwen3-32b --shape train_4k --mesh pod

@@ -1,11 +1,8 @@
 """Test-support subsystems that ship with the runtime (not under tests/):
 deterministic fault injection (``repro.testing.faults``) is imported by
 production code at named sites, so recovery paths are exercisable on demand
-from tests, CI gates, and chaos drills alike (DESIGN.md §10), and
-``repro.testing.proptest`` is the offline fallback property-test engine
-that keeps the hypothesis property modules running (never skipped) in
-containers where hypothesis cannot be installed (DESIGN.md §13)."""
+from tests and chaos drills alike (DESIGN.md §10)."""
 
-from . import faults, proptest
+from . import faults
 
-__all__ = ["faults", "proptest"]
+__all__ = ["faults"]

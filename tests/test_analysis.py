@@ -161,6 +161,8 @@ def test_verify_green_lu_all_graphs(graph):
     _drain_lu(d)
     assert d.stats["verified_scopes"] >= 1
     assert d.executor.verify
+    if graph != "g1":  # g1 runs leaves eagerly: there is no plan to prove
+        assert d.executor.stats["verified_plans"] >= 1
 
 
 @pytest.mark.parametrize(
@@ -198,6 +200,11 @@ def test_verify_green_stacked(n_roots, monkeypatch):
 def test_verify_env_off_by_default(monkeypatch):
     monkeypatch.delenv("REPRO_VERIFY", raising=False)
     assert not Dispatcher(graph="g2").verify
+    # verification off adds no work: no scope or plan is proved
+    d = Dispatcher(graph="g2")
+    _drain_lu(d)
+    assert d.stats["verified_scopes"] == 0
+    assert d.executor.stats.get("verified_plans", 0) == 0
     monkeypatch.setenv("REPRO_VERIFY", "0")
     assert not Dispatcher(graph="g2").verify
     monkeypatch.setenv("REPRO_VERIFY", "1")
@@ -224,10 +231,15 @@ def test_replay_skips_verification_entirely():
     _drain_lu(d, seed=0)
     scopes = d.stats["verified_scopes"]
     stats = verifier_stats()
+    ex = dict(d.executor.stats)
     _drain_lu(d, seed=1)  # memo replay
     assert d.stats["memo_hits"] == 1
     assert d.stats["verified_scopes"] == scopes
     assert verifier_stats() == stats
+    # and the replay is pure: one launch, no compile, no plan re-proved
+    assert d.executor.stats["launches"] == ex["launches"] + 1
+    for k in ("compiles", "verified_plans"):
+        assert d.executor.stats[k] == ex[k]
 
 
 # -- mutation faults: the verifier detects what it claims to -------------------

@@ -8,10 +8,9 @@ slow recovery, the HEALTHY/DEGRADED/DRAINING health machine with graceful
 property: a randomized multi-site fault schedule (raise + stall + OOM
 across ticks, overlap on and off) must end with every submitted future
 resolved-or-typed-failed, no lost futures, no wedged tick, and every
-breaker back to CLOSED once faults clear.
-
-When hypothesis is absent (offline CI container) the vendored fallback
-engine runs the same property — these tests never skip (DESIGN.md §13).
+breaker back to CLOSED once faults clear.  The fixed chaos schedule
+(``test_chaos_schedule_contract``) runs all three mechanisms in one server
+and checks each of its exact contracts as one case.
 """
 
 import time
@@ -32,10 +31,7 @@ from repro.errors import (
 from repro.serve import BatchServer
 from repro.testing import faults
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline container: vendored fallback (DESIGN.md §13)
-    from repro.testing.proptest import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 
 @pytest.fixture(autouse=True)
@@ -462,3 +458,170 @@ def test_chaos_every_future_resolves_or_fails_typed(plan, overlap):
     rep = _tick_healthy(srv, n=2, seed0=5000)
     rep = _tick_healthy(srv, n=2, seed0=6000)
     assert rep.compiles == 0 and rep.failed == 0
+
+
+# -- fixed chaos schedule --------------------------------------------------------
+
+_WATCHDOG_S = 0.3
+_STALL_S = 1.0
+
+
+@pytest.fixture(scope="module")
+def chaos_schedule():
+    """One server through a deterministic, seeded fault schedule that runs
+    all three self-healing mechanisms: a poisoned bucket that trips its
+    breaker and re-closes it, a fence stall past the watchdog budget, an
+    OOM on a full stacked chunk, then seeded transient faults, recovery,
+    and three steady-state ticks.  Returns what the contract cases read."""
+    clear_compile_cache()
+    rng = np.random.default_rng(0)
+    srv = BatchServer(
+        graph="g2",
+        max_batch=4,
+        max_retries=1,
+        watchdog_s=_WATCHDOG_S,
+        breaker_threshold=2,
+        breaker_cooldown=2,
+        degrade_recovery=2,
+        retry_jitter_seed=7,
+    )
+    # a tick that blocks longer than budget + every injected delay + slack
+    # has wedged: nothing in the schedule can legitimately take this long
+    wedge_budget_s = _WATCHDOG_S + _STALL_S + 30.0
+    all_futs = []
+    seed = 0
+    wedged = 0
+
+    def tick():
+        nonlocal wedged
+        t0 = time.perf_counter()
+        srv.tick()
+        if time.perf_counter() - t0 > wedge_budget_s:
+            wedged += 1
+
+    def submit(kind):
+        nonlocal seed
+        submit_fn = _submit_lu if kind == "lu" else _submit_chol
+        all_futs.append(submit_fn(srv, seed=seed))
+        seed += 1
+
+    # phase 0 — warmup: capture both buckets' programs healthy
+    for _ in range(2):
+        submit("lu")
+        submit("chol")
+    tick()
+
+    # phase 1 — poisoned chol bucket: every drain raises until the breaker
+    # trips (threshold 2), then the fault clears and the cooldown + probe
+    # must complete the round trip
+    with faults.inject(
+        "serve.drain",
+        lambda: RuntimeError("chaos: poisoned bucket"),
+        when=lambda ctx: ctx["op"] == "potrf",
+        times=None,
+    ):
+        for _ in range(3):
+            submit("chol")
+            submit("lu")  # healthy bystander bucket: must keep resolving
+            tick()
+    for _ in range(4):  # cooldown ticks + half-open probe + re-close
+        submit("chol")
+        tick()
+
+    # phase 2 — fence stall: the watchdog must fail the chunk typed
+    # within budget instead of blocking the tick on the hung fence
+    submit("lu")
+    submit("lu")
+    with faults.inject("drain.stall", delay_s=_STALL_S):
+        tick()
+
+    # phase 3 — device OOM on a full stacked chunk: split halves resolve
+    # the same tick, the bucket's cap degrades then recovers
+    for _ in range(4):
+        submit("lu")
+    with faults.inject(
+        "launch.oom", lambda: ResourceExhausted("RESOURCE_EXHAUSTED: chaos")
+    ):
+        tick()
+
+    # phase 4 — seeded random transient faults (retry + bisect load)
+    for _ in range(2):
+        for _ in range(int(rng.integers(1, 4))):
+            submit("lu")
+        n_raises = int(rng.integers(0, 3))
+        if n_raises:
+            with faults.inject(
+                "serve.drain",
+                lambda: RuntimeError("chaos: transient"),
+                times=n_raises,
+            ):
+                tick()
+        else:
+            tick()
+
+    # phase 5 — recovery: healthy traffic until queue empty, breakers
+    # closed, degradation recovered
+    for _ in range(12):
+        submit("lu")
+        submit("chol")
+        tick()
+        if (
+            srv.pending() == 0
+            and srv.health() == "HEALTHY"
+            and all(f.done for f in all_futs)
+        ):
+            break
+
+    # phase 6 — steady state: the §7 replay contract must hold again; the
+    # first steady tick may still recompile, so the last one is read
+    for _ in range(3):
+        before = dict(srv.stats)
+        for kind in ("lu", "lu", "chol", "chol"):
+            submit(kind)
+        tick()
+    steady = {k: srv.stats[k] - before[k]
+              for k in ("compiles", "launches", "failed", "drains")}
+
+    ends = [
+        "lost_futures" if not f.done
+        else "resolved" if f.exception() is None
+        else "typed_failed" if isinstance(f.exception(), ServeError)
+        else "untyped_failed"
+        for f in all_futs
+    ]
+    return dict(
+        {k: ends.count(k) for k in
+         ("resolved", "typed_failed", "untyped_failed", "lost_futures")},
+        submitted=len(all_futs),
+        wedged_ticks=wedged,
+        breaker_round_trips=srv.breaker_round_trips(),
+        watchdog_fires=srv.stats["watchdog_fires"],
+        oom_events=srv.stats["oom_events"],
+        final_health=srv.health(),
+        steady_state=steady,
+    )
+
+
+_CHAOS_CONTRACT = {
+    # every submitted future ends resolved or typed-failed
+    "lost_futures": lambda r: r["lost_futures"] == 0,
+    "untyped_failed": lambda r: r["untyped_failed"] == 0,
+    "accounting_ok": lambda r: r["resolved"] + r["typed_failed"]
+    + r["untyped_failed"] == r["submitted"],
+    # no tick blocked past the watchdog budget plus the injected delays
+    "wedged_ticks": lambda r: r["wedged_ticks"] == 0,
+    # every self-healing mechanism witnessed at least once
+    "breaker_round_trips": lambda r: r["breaker_round_trips"] >= 1,
+    "watchdog_fires": lambda r: r["watchdog_fires"] >= 1,
+    "oom_events": lambda r: r["oom_events"] >= 1,
+    # recovered: breakers closed, caps restored, one launch per bucket
+    "final_health_healthy": lambda r: r["final_health"] == "HEALTHY",
+    "steady_state_ok": lambda r: r["steady_state"]["compiles"] == 0
+    and r["steady_state"]["failed"] == 0
+    and r["steady_state"]["launches"] == r["steady_state"]["drains"] == 2,
+}
+
+
+@pytest.mark.parametrize("gate", list(_CHAOS_CONTRACT))
+def test_chaos_schedule_contract(chaos_schedule, gate):
+    assert _CHAOS_CONTRACT[gate](chaos_schedule), chaos_schedule

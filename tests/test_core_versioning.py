@@ -9,10 +9,7 @@ sequential program order semantics when executed.
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline container: vendored fallback (DESIGN.md §13)
-    from repro.testing.proptest import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import Access, DepTracker, GData, GTask, Operation
 

@@ -137,6 +137,7 @@ def test_launch_failure_then_clean_retry():
     with faults.inject("executor.launch", RuntimeError("device lost")):
         with pytest.raises(RuntimeError, match="device lost"):
             run_lu(a, partitions=((2, 2),))
+    assert drain_memo_stats()["entries"] == 1  # no half-captured second
     l, u = run_lu(a, partitions=((2, 2),))
     np.testing.assert_allclose(np.asarray(l), rl, rtol=1e-6)
     np.testing.assert_allclose(np.asarray(u), ru, rtol=1e-6)
@@ -148,10 +149,12 @@ def test_leaf_kernel_failure_propagates_and_recovers():
     with faults.inject("leaf.fn", RuntimeError("bad kernel")):
         with pytest.raises(RuntimeError, match="bad kernel"):
             run_lu(a, partitions=((2, 2),))
+    assert drain_memo_stats()["entries"] == 0  # nothing half-captured
     l, u = run_lu(a, partitions=((2, 2),))
     np.testing.assert_allclose(
         np.asarray(l) @ np.asarray(u), np.asarray(a), rtol=2e-4, atol=2e-4
     )
+    assert drain_memo_stats()["entries"] == 1  # the recovery is memoized
 
 
 def test_capture_failure_leaves_memo_unchanged():

@@ -17,11 +17,16 @@ import jax.numpy as jnp
 
 from repro.core import Access, Dispatcher, DepTracker, GData, GTask, Operation
 from repro.core import dd_matrix, spd_matrix
-from repro.core.executors import clear_compile_cache, plan_schedule
+from repro.core.executors import (
+    clear_compile_cache,
+    drain_memo_stats,
+    plan_schedule,
+)
 from repro.core.executors.jit_wave import _DRAIN_MEMO
 from repro.linalg import run_cholesky, run_lu, run_lu_many
 from repro.linalg.cholesky import utp_cholesky
 from repro.linalg.lu import utp_getrf, utp_lu_solve, utp_solve
+from repro.testing import faults
 
 
 # --------------------------------------------------------------------------
@@ -163,7 +168,12 @@ def test_run_lu_many_replays_with_zero_recompiles():
     outs1 = run_lu_many(mats, partitions=((p, p),))
     # structurally repeated multi-root drain on fresh values: pure replay
     mats2 = [dd_matrix(n, seed=s) for s in (33, 34)]
-    outs2 = run_lu_many(mats2, partitions=((p, p),))
+    misses = drain_memo_stats()["misses"]
+    with faults.inject("executor.launch", record=True, times=None) as probe:
+        outs2 = run_lu_many(mats2, partitions=((p, p),))
+    # one launch, and it replays the captured program: nothing recompiles
+    assert [e["replay"] for e in probe.log] == [True]
+    assert drain_memo_stats()["misses"] == misses
     for (l, u), m in zip(outs1 + outs2, mats + mats2):
         np.testing.assert_allclose(
             np.asarray(l) @ np.asarray(u), np.asarray(m), rtol=2e-4, atol=2e-4
@@ -212,7 +222,7 @@ def test_lu_solve_overlaps_solve_groups_with_factor_groups():
     utp_lu_solve(d, A, B)
     d.run()
     st = d.executor.stats
-    assert st["launches"] == 1
+    assert st["launches"] == 1 and st["compiles"] == 1
     # solve groups fused into factor groups: single-root lu_solve has slack
     # (contrast test_single_root_lu_is_at_its_chain_lower_bound below)
     assert st["groups"] < st["groups_prefusion"]
@@ -224,6 +234,12 @@ def test_lu_solve_overlaps_solve_groups_with_factor_groups():
     np.testing.assert_allclose(
         np.asarray(B.value), np.asarray(B3.value), rtol=2e-4, atol=2e-4
     )
+    # a structurally repeated composed drain replays: one launch, 0 compiles
+    d = Dispatcher(graph="g2")
+    utp_lu_solve(d, fresh(dd_matrix(n, seed=52)), fresh(b))
+    d.run()
+    assert d.executor.stats["launches"] == 1
+    assert d.executor.stats.get("compiles", 0) == 0
 
 
 def test_single_root_lu_is_at_its_chain_lower_bound():

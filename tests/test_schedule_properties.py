@@ -7,9 +7,7 @@ are mutually independent, and slot-launch semantics (gather all reads,
 then scatter all writes) reproduce the sequential program order exactly.
 
 Separate module from test_schedule_fusion so the property machinery stays
-out of the deterministic tests' import path.  When hypothesis is absent
-(offline CI container) the vendored fallback engine runs the same
-properties — these tests never skip (DESIGN.md §13).
+out of the deterministic tests' import path.
 """
 
 import numpy as np
@@ -19,10 +17,7 @@ from repro.core.executors import plan_schedule
 
 from test_schedule_fusion import _track, mktask
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline container: vendored fallback (DESIGN.md §13)
-    from repro.testing.proptest import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 
 @st.composite

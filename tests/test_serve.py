@@ -108,6 +108,7 @@ def test_repeat_tick_replays_zero_compiles_one_launch():
         assert rep.compiles == 0, rep
         assert rep.launches == 1 and rep.stacked_drains == 1
         assert rep.memo_hits == 1 and rep.memo_misses == 0
+        assert rep.host_idle_us == 0.0  # no check_finite: never fences
         for b in rep.per_bucket:
             assert b["compiles"] == 0 and b["launches"] == 1
 
@@ -436,3 +437,70 @@ def test_tick_reports_latency_percentiles():
     assert rep.p50_ms >= 250.0 and rep.p99_ms >= rep.p50_ms
     pct = srv.latency_percentiles()
     assert pct["samples"] == 3 and pct["p50_ms"] >= 250.0
+
+
+# -- overload: shedding, retries and isolation in one run ----------------------
+
+
+@pytest.fixture(scope="module")
+def overload_run():
+    """A burst 8 requests past ``max_pending`` (those 8 shed with
+    RejectedError), then 8 more with one deterministically poisoned: bisect
+    isolates it and its one retry exhausts into DrainError, while every
+    healthy request resolves.  Returns what the contract cases read."""
+    clear_compile_cache()
+    n, max_pending = 32, 12
+    srv = BatchServer(
+        graph="g2",
+        max_batch=8,
+        max_pending=max_pending,
+        overload_policy="reject",
+        max_retries=1,
+        retry_backoff=1,
+    )
+    burst = max_pending + 8
+    futs = [
+        srv.lu(dd_matrix(n, seed=s), partitions=((2, 2),))
+        for s in range(burst)
+    ]
+    srv.tick()
+    poison = [
+        srv.lu(dd_matrix(n, seed=100 + s), partitions=((2, 2),))
+        for s in range(8)
+    ]
+    target = poison[3].rid
+    with faults.inject(
+        "serve.drain",
+        RuntimeError("injected: lane poisoned"),
+        when=lambda ctx: target in ctx["rids"],
+        times=None,
+    ):
+        srv.tick()  # bisects; the poisoned request consumes its retry
+        while srv.pending():
+            srv.tick()  # backoff ticks, then the retry exhausts
+    resolved = sum(
+        1 for f in futs + poison if f.done and f.exception() is None
+    )
+    return dict(
+        srv.stats,
+        submitted=burst + 8,
+        resolved=resolved,
+        latency=srv.latency_percentiles(),
+    )
+
+
+_OVERLOAD_CONTRACT = {
+    "shed": lambda r: r["shed"] >= 1,
+    "retried": lambda r: r["retried"] >= 1,
+    "failed": lambda r: r["failed"] >= 1,
+    # every request not shed or failed resolved, with latencies recorded
+    "accounting": lambda r: r["resolved"]
+    == r["submitted"] - r["shed"] - r["failed"]
+    and r["latency"]["samples"] > 0
+    and r["latency"]["p99_ms"] >= r["latency"]["p50_ms"] > 0,
+}
+
+
+@pytest.mark.parametrize("gate", list(_OVERLOAD_CONTRACT))
+def test_overload_contract(overload_run, gate):
+    assert _OVERLOAD_CONTRACT[gate](overload_run), overload_run

@@ -9,9 +9,7 @@ XLA; observed differences are ~1 ulp and the assertion uses a 1e-6
 tolerance several orders tighter than the factorization's own error.
 
 Separate module from test_serve so the property machinery stays out of
-the deterministic serving tests' import path.  When hypothesis is absent
-(offline CI container) the vendored fallback engine runs the same
-properties — these tests never skip (DESIGN.md §13).
+the deterministic serving tests' import path.
 """
 
 import numpy as np
@@ -27,10 +25,7 @@ from repro.linalg import (
 )
 from repro.serve import BatchServer
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline container: vendored fallback (DESIGN.md §13)
-    from repro.testing.proptest import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 
 @settings(max_examples=12, deadline=None)
